@@ -25,7 +25,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.core.merge import Partial
 from repro.core.routing import route_fanout, route_pairwise, route_ring
 from repro.distributed.hlo_costs import analyse_hlo
@@ -33,14 +33,14 @@ from repro.models.mla import MLAConfig
 
 CFG = MLAConfig()
 NI, B, S_LOCAL = 8, 32, 2048
-mesh = compat.make_mesh((NI,), ("instance",))
+mesh = make_mesh((NI,), ("instance",))
 q = jax.ShapeDtypeStruct((NI * B, CFG.n_heads, CFG.d_qk), jnp.bfloat16)
 ckv = jax.ShapeDtypeStruct((NI * S_LOCAL, CFG.d_qk), jnp.bfloat16)
 valid = jax.ShapeDtypeStruct((NI * S_LOCAL,), jnp.bool_)
 out = {}
 
 def compile_and_count(name, fn, specs, out_specs, args):
-    sm = jax.jit(compat.shard_map(fn, mesh=mesh, in_specs=specs,
+    sm = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=specs,
                                out_specs=out_specs))
     c = analyse_hlo(sm.lower(*args).compile().as_text(), NI)
     out[name] = {"wire": c.collective_wire_bytes,
@@ -73,6 +73,9 @@ def run():
     env = dict(os.environ)
     env["PYTHONPATH"] = str(ROOT / "src")
     env.pop("XLA_FLAGS", None)
+    # the child reads compiled collective bytes, not time: keep it on the
+    # CPU so it never contends with a parent process that holds the chip
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", _PROG], capture_output=True,
                        text=True, env=env, cwd=str(ROOT), timeout=900)
     assert r.returncode == 0, r.stderr[-2000:]

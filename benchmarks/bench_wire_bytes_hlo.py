@@ -20,7 +20,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.core.merge import Partial
 from repro.core.routing import route_fanout, route_pairwise
 from repro.core.splice import fetch_chunk
@@ -29,7 +29,7 @@ from repro.models.mla import MLAConfig
 
 CFG = MLAConfig()                      # real V2 geometry: d_qk=576, d_v=512
 NI, B, S_LOCAL, CT = 8, 32, 2048, 2048
-mesh = compat.make_mesh((NI,), ("instance",))
+mesh = make_mesh((NI,), ("instance",))
 
 def route_prog(q, ckv, valid):
     return route_pairwise(CFG, q, ckv,
@@ -47,7 +47,7 @@ ckv = jax.ShapeDtypeStruct((NI * S_LOCAL, CFG.d_qk), jnp.bfloat16)
 valid = jax.ShapeDtypeStruct((NI * S_LOCAL,), jnp.bool_)
 pool = jax.ShapeDtypeStruct((NI * S_LOCAL, CFG.d_qk), jnp.bfloat16)
 
-sm = jax.jit(compat.shard_map(route_prog, mesh=mesh,
+sm = jax.jit(jax.shard_map(route_prog, mesh=mesh,
                            in_specs=(P("instance"), P("instance"),
                                      P("instance")),
                            out_specs=Partial(o=P("instance"),
@@ -58,7 +58,7 @@ c = analyse_hlo(txt, NI)
 out["route"] = {"wire": c.collective_wire_bytes,
                 "result": c.collective_result_bytes}
 
-sm2 = jax.jit(compat.shard_map(fetch_prog, mesh=mesh,
+sm2 = jax.jit(jax.shard_map(fetch_prog, mesh=mesh,
                             in_specs=(P("instance"), P("instance")),
                             out_specs=P("instance")))
 txt2 = sm2.lower(pool, ckv).compile().as_text()
@@ -75,6 +75,9 @@ def run():
     env = dict(os.environ)
     env["PYTHONPATH"] = str(ROOT / "src")
     env.pop("XLA_FLAGS", None)
+    # the child reads compiled collective bytes, not time: keep it on the
+    # CPU so it never contends with a parent process that holds the chip
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", _PROG], capture_output=True,
                        text=True, env=env, cwd=str(ROOT), timeout=600)
     assert r.returncode == 0, r.stderr[-2000:]

@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
 from repro.core.merge import Partial, merge2, merge_stacked, merge_tree
 from repro.models.mla import MLAConfig, absorbed_partial
 
@@ -211,7 +210,7 @@ def route_ring(cfg: MLAConfig, q_abs: jax.Array, local_ckv: jax.Array,
     home with the full merge. Overlaps transfer with compute (beyond-paper;
     the TPU-native schedule for all-holders attention)."""
     check_route_shards(axis, q_abs, local_ckv, local_valid)
-    m_size = compat.axis_size(axis)
+    m_size = lax.axis_size(axis)
     perm = [(i, (i + 1) % m_size) for i in range(m_size)]
 
     def hop(carry, _):
@@ -227,7 +226,7 @@ def route_ring(cfg: MLAConfig, q_abs: jax.Array, local_ckv: jax.Array,
     ident = Partial.identity(q_abs.shape[:-1], cfg.kv_lora_rank)
     # the identity carry is device-invariant; mark it varying over the
     # instance axis so the scan carry types line up under shard_map
-    ident = jax.tree.map(lambda x: compat.pvary(x, (axis,)), ident)
+    ident = jax.tree.map(lambda x: lax.pcast(x, axis, to="varying"), ident)
     (q, acc), _ = lax.scan(hop, (q_abs, ident), None, length=m_size)
     return acc
 
@@ -260,7 +259,7 @@ def route_pairwise_tpla(cfg: MLAConfig, q_abs_slice: jax.Array,
     p = jnp.exp(logits - m[..., None])
     l = jnp.sum(p, axis=-1)
     # Each rank holds d_c/N value columns; output slice stays rank-local.
-    n_tp = compat.axis_size(tp_axis)
+    n_tp = lax.axis_size(tp_axis)
     v_cols = local_ckv_slice[:, :cfg.kv_lora_rank // n_tp].astype(jnp.float32)
     o_slice = jnp.einsum("bhs,sd->bhd", p / l[..., None], v_cols)
     back = Partial(

@@ -15,8 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
-
 
 def allgather_matmul_overlapped(x_shard: jax.Array, w_block: jax.Array,
                                 axis: str) -> jax.Array:
@@ -26,7 +24,7 @@ def allgather_matmul_overlapped(x_shard: jax.Array, w_block: jax.Array,
     w_block (d, n_block) — this rank's column block r of W.
     Returns y_local (m_local, P * n_block) = x_shard @ W (all columns).
     """
-    p = compat.axis_size(axis)
+    p = lax.axis_size(axis)
     r = lax.axis_index(axis)
     n_block = w_block.shape[1]
     perm = [(j, (j + 1) % p) for j in range(p)]
@@ -44,7 +42,7 @@ def allgather_matmul_overlapped(x_shard: jax.Array, w_block: jax.Array,
     acc0 = jnp.zeros((x_shard.shape[0], p * n_block), jnp.float32)
     # the zero init is device-invariant; mark it varying over the ring axis
     # so the fori_loop carry types match under shard_map
-    acc0 = compat.pvary(acc0, (axis,))
+    acc0 = lax.pcast(acc0, axis, to="varying")
     acc, _ = lax.fori_loop(0, p, body, (acc0, w_block))
     return acc
 

@@ -109,6 +109,4 @@ def flops_and_bytes(cost_analysis: Optional[dict]) -> tuple:
     if not cost_analysis:
         return 0.0, 0.0
     ca = cost_analysis
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
     return float(ca.get("flops", 0.0)), float(ca.get("bytes accessed", 0.0))

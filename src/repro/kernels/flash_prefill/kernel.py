@@ -11,6 +11,7 @@ sees a tall-skinny (H*BQ, D) @ (D, BK) matmul with D = 576.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -45,12 +46,15 @@ def _kernel(q_ref, ckv_ref, o_ref, acc, m_scr, l_scr,
         scores = jax.lax.dot_general(
             qf, kv, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (BQ*H, BK)
-        qpos = (q_idx * block_q
-                + jax.lax.broadcasted_iota(jnp.int32, (BQ, H), 0)
-                + (sk - sq)).reshape(BQ * H)
+        # row r of the folded tile is query position q0 + r // H. Causality
+        # kpos <= q0 + r // H is tested as r >= (kpos - q0) * H, which is
+        # the same for integers: 2-D iotas and a multiply, no (BQ, H) ->
+        # (BQ*H, 1) reshape, which Mosaic cannot lower
+        q0 = q_idx * block_q + (sk - sq)
+        row = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
         kpos = k_start + jax.lax.broadcasted_iota(
             jnp.int32, scores.shape, 1)
-        scores = jnp.where(kpos <= qpos[:, None], scores, NEG_INF)
+        scores = jnp.where(row >= (kpos - q0) * H, scores, NEG_INF)
 
         m_prev, l_prev = m_scr[...], l_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1))
@@ -71,12 +75,21 @@ def _kernel(q_ref, ckv_ref, o_ref, acc, m_scr, l_scr,
         o_ref[0] = (acc[...] / denom[:, None]).reshape(BQ, H, d_v)
 
 
+# Folded q rows (block_q * H) per tile when block_q is not given: the f32
+# (rows, block_k) score tile, its exp and the (rows, d_v) accumulator must
+# fit the 16 MiB scoped VMEM. At H=128, D=576, block_k=512 a v5e compile
+# fits 8 queries (1024 rows) and refuses 16.
+FOLDED_ROWS = 1024
+
+
 def flash_prefill_pallas(q: jax.Array, ckv: jax.Array, d_v: int,
-                         scale: float, block_q: int = 128,
+                         scale: float, block_q: Optional[int] = None,
                          block_k: int = 512, interpret: bool = True):
     """q (B, Sq, H, D); ckv (B, Sk, D) with Sq <= Sk, tail-aligned causal."""
     B, Sq, H, D = q.shape
     Sk = ckv.shape[1]
+    if block_q is None:
+        block_q = max(1, FOLDED_ROWS // H)
     block_q = min(block_q, Sq)
     block_k = min(block_k, Sk)
     assert Sq % block_q == 0 and Sk % block_k == 0

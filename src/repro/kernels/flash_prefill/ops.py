@@ -14,7 +14,7 @@ from repro.kernels.flash_prefill.kernel import flash_prefill_pallas
 @functools.partial(jax.jit, static_argnames=("d_v", "scale", "block_q",
                                              "block_k", "interpret"))
 def flash_prefill(q: jax.Array, ckv: jax.Array, *, d_v: int = 512,
-                  scale: float = 1.0, block_q: int = 128,
+                  scale: float = 1.0, block_q: Optional[int] = None,
                   block_k: int = 512,
                   interpret: Optional[bool] = None) -> jax.Array:
     """Causal absorbed-MLA attention: q (B,Sq,H,D) over ckv (B,Sk,D)."""

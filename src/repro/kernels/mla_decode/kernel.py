@@ -27,8 +27,9 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = float("-inf")
 
 
-def _kernel(q_ref, ckv_ref, len_ref, o_ref, m_ref, l_ref,
+def _kernel(len_ref, q_ref, ckv_ref, o_ref, m_ref, l_ref,
             acc, m_scr, l_scr, *, scale: float, d_v: int, block_s: int):
+    b_idx = pl.program_id(0)
     s_idx = pl.program_id(1)
     ns = pl.num_programs(1)
 
@@ -45,15 +46,17 @@ def _kernel(q_ref, ckv_ref, len_ref, o_ref, m_ref, l_ref,
         preferred_element_type=jnp.float32) * scale   # (H, BS)
     # residency mask for the ragged tail (valid cache length per batch row)
     valid = (s_idx * block_s + jax.lax.broadcasted_iota(
-        jnp.int32, scores.shape, 1)) < len_ref[0]
+        jnp.int32, scores.shape, 1)) < len_ref[b_idx]
     scores = jnp.where(valid, scores, NEG_INF)
 
+    # m/l stay (H, 1) columns end to end: the row reductions keep their
+    # sublane layout, so no relayout is needed to store them
     m_prev, l_prev = m_scr[...], l_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1))
+    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)                   # exp(-inf - m) = 0 ok
-    p = jnp.exp(scores - m_new[:, None])
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1)
-    acc[...] = acc[...] * alpha[:, None] + jax.lax.dot_general(
+    p = jnp.exp(scores - m_new)
+    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc[...] = acc[...] * alpha + jax.lax.dot_general(
         p, ckv[:, :d_v], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     m_scr[...], l_scr[...] = m_new, l_new
@@ -62,9 +65,10 @@ def _kernel(q_ref, ckv_ref, len_ref, o_ref, m_ref, l_ref,
     def _finish():
         l = l_scr[...]
         denom = jnp.where(l > 0, l, 1.0)
-        o_ref[0] = acc[...] / denom[:, None]
+        o_ref[0] = acc[...] / denom
         m_ref[0] = m_scr[...]
         l_ref[0] = l
+
 
 def mla_decode_pallas(q: jax.Array, ckv: jax.Array, lengths: jax.Array,
                       d_v: int, scale: float, block_s: int = 512,
@@ -74,31 +78,33 @@ def mla_decode_pallas(q: jax.Array, ckv: jax.Array, lengths: jax.Array,
     S = ckv.shape[1]
     block_s = min(block_s, S)
     assert S % block_s == 0, (S, block_s)
-    grid = (B, S // block_s)
     kernel = functools.partial(_kernel, scale=scale, d_v=d_v,
                                block_s=block_s)
-    out_shape = (jax.ShapeDtypeStruct((B, H, d_v), jnp.float32),
-                 jax.ShapeDtypeStruct((B, H), jnp.float32),
-                 jax.ShapeDtypeStruct((B, H), jnp.float32))
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
+    # lengths ride in SMEM as a scalar-prefetch operand. m/l leave the
+    # kernel as (B, H, 1) so every block's last two dims are (H, 1): whole
+    # array dims or tile multiples, as Mosaic requires for any batch size.
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(B, S // block_s),
         in_specs=[
-            pl.BlockSpec((1, H, D), lambda b, s: (b, 0, 0)),
-            pl.BlockSpec((1, block_s, D), lambda b, s: (b, s, 0)),
-            pl.BlockSpec((1,), lambda b, s: (b,),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, H, D), lambda b, s, lens: (b, 0, 0)),
+            pl.BlockSpec((1, block_s, D), lambda b, s, lens: (b, s, 0)),
         ],
         out_specs=(
-            pl.BlockSpec((1, H, d_v), lambda b, s: (b, 0, 0)),
-            pl.BlockSpec((1, H), lambda b, s: (b, 0)),
-            pl.BlockSpec((1, H), lambda b, s: (b, 0)),
+            pl.BlockSpec((1, H, d_v), lambda b, s, lens: (b, 0, 0)),
+            pl.BlockSpec((1, H, 1), lambda b, s, lens: (b, 0, 0)),
+            pl.BlockSpec((1, H, 1), lambda b, s, lens: (b, 0, 0)),
         ),
-        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((H, d_v), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
         ],
-        interpret=interpret,
-    )(q, ckv, lengths)
+    )
+    out_shape = (jax.ShapeDtypeStruct((B, H, d_v), jnp.float32),
+                 jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
+                 jax.ShapeDtypeStruct((B, H, 1), jnp.float32))
+    o, m, l = pl.pallas_call(kernel, grid_spec=grid_spec,
+                             out_shape=out_shape,
+                             interpret=interpret)(lengths, q, ckv)
+    return o, m[..., 0], l[..., 0]

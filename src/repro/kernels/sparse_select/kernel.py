@@ -36,12 +36,13 @@ def _kernel(idx_ref, q_ref, ckv_ref, o_ref, m_ref, l_ref,
         q, blk, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale   # (H, BLOCK)
 
+    # m/l stay (H, 1) columns, as in kernels/mla_decode
     m_prev, l_prev = m_scr[...], l_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1))
+    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(scores - m_new[:, None])
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1)
-    acc[...] = acc[...] * alpha[:, None] + jax.lax.dot_general(
+    p = jnp.exp(scores - m_new)
+    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc[...] = acc[...] * alpha + jax.lax.dot_general(
         p, blk[:, :d_v], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     m_scr[...], l_scr[...] = m_new, l_new
@@ -50,7 +51,7 @@ def _kernel(idx_ref, q_ref, ckv_ref, o_ref, m_ref, l_ref,
     def _finish():
         l = l_scr[...]
         denom = jnp.where(l > 0, l, 1.0)
-        o_ref[0] = acc[...] / denom[:, None]
+        o_ref[0] = acc[...] / denom
         m_ref[0] = m_scr[...]
         l_ref[0] = l
 
@@ -75,17 +76,21 @@ def sparse_select_pallas(q: jax.Array, ckv: jax.Array, block_idx: jax.Array,
         ],
         out_specs=(
             pl.BlockSpec((1, H, d_v), lambda b, k, idx: (b, 0, 0)),
-            pl.BlockSpec((1, H), lambda b, k, idx: (b, 0)),
-            pl.BlockSpec((1, H), lambda b, k, idx: (b, 0)),
+            pl.BlockSpec((1, H, 1), lambda b, k, idx: (b, 0, 0)),
+            pl.BlockSpec((1, H, 1), lambda b, k, idx: (b, 0, 0)),
         ),
         scratch_shapes=[
             pltpu.VMEM((H, d_v), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
-            pltpu.VMEM((H,), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
         ],
     )
+    # m/l leave as (B, H, 1): a (1, H) block of a (B, H) array breaks the
+    # TPU tiling rule once B > 1
     out_shape = (jax.ShapeDtypeStruct((B, H, d_v), jnp.float32),
-                 jax.ShapeDtypeStruct((B, H), jnp.float32),
-                 jax.ShapeDtypeStruct((B, H), jnp.float32))
-    return pl.pallas_call(kernel, grid_spec=grid_spec, out_shape=out_shape,
-                          interpret=interpret)(block_idx, q, ckv)
+                 jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
+                 jax.ShapeDtypeStruct((B, H, 1), jnp.float32))
+    o, m, l = pl.pallas_call(kernel, grid_spec=grid_spec,
+                             out_shape=out_shape,
+                             interpret=interpret)(block_idx, q, ckv)
+    return o, m[..., 0], l[..., 0]

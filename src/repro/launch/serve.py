@@ -9,6 +9,10 @@ pluggable execution backend (ISSUE 3):
     # plan AND execute on real c^KV arrays, verifying §3.3 exactness
     PYTHONPATH=src python -m repro.launch.serve --backend exec --verify
 
+    # ... at DeepSeek-V2 attention widths in bf16 (the chip's geometry)
+    PYTHONPATH=src python -m repro.launch.serve --backend exec --verify \
+        --mla deepseek-v2 --dtype bfloat16
+
     # the §5.4 selection regime end-to-end (ISSUE 4): the distributed
     # indexer scores/selects per step, the backends scatter-attend the
     # masks, selection requests verify against the selection_k oracle
@@ -38,6 +42,8 @@ benchmarks do.
 """
 
 import argparse
+import os
+import pathlib
 
 import numpy as np
 
@@ -86,9 +92,19 @@ def build_parser() -> argparse.ArgumentParser:
                     help="replay a save_trace() JSON instead of generating")
     ap.add_argument("--save-trace", default="",
                     help="write the generated trace as JSON and run it")
-    ap.add_argument("--verify", action="store_true",
+    ap.add_argument("--verify", nargs="?", type=float, const=True,
+                    default=None, metavar="TOL",
                     help="exec backend: check outputs against the "
-                         "single-instance attention oracle (§3.3)")
+                         "single-instance attention oracle (§3.3) and exit "
+                         "non-zero when a step's max|err| exceeds TOL "
+                         "(default: jax_exec.oracle_tolerance of --dtype)")
+    ap.add_argument("--mla", choices=("tiny", "deepseek-v2"), default="tiny",
+                    help="execution geometry of the exec backends "
+                         "(jax_exec.MLA_GEOMETRIES); the planner's payload "
+                         "does not depend on it")
+    ap.add_argument("--dtype", choices=("float32", "bfloat16"),
+                    default="float32",
+                    help="dtype of the exec backends' cache and queries")
     ap.add_argument("--fabric-table", default="",
                     help="JSON fabric table (calibrate_fabric output) to "
                          "register before building the engine")
@@ -161,15 +177,35 @@ def build_selector(args):
     return None
 
 
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    JAX_COMPILATION_CACHE_DIR, when set, is the cache and no other
+    directory is set. Otherwise the cache is <checkout>/.jax_cache, a
+    fixed path, so that a later run in the same checkout finds what an
+    earlier one wrote. Every compile is written, however short: the
+    served path compiles many small programs, and the default one-second
+    floor would cache none of them. Call before the first compile."""
+    import jax
+    path = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or str(pathlib.Path(__file__).resolve().parents[3]
+                   / ".jax_cache"))
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
+
+
 def build_engine(args) -> ServingEngine:
     if args.fabric_table:
         register_fabrics(Fabric.load_table(args.fabric_table))
-    if args.backend == "exec":
-        from repro.serving.backends import JaxExecBackend
-        backend = JaxExecBackend()
-    elif args.backend == "shard_map":
-        from repro.serving.backends import ShardMapExecBackend
-        backend = ShardMapExecBackend(fused=not args.serial_exec)
+    if args.backend in ("exec", "shard_map"):
+        import jax.numpy as jnp
+        from repro.serving.backends import (JaxExecBackend,
+                                            ShardMapExecBackend)
+        from repro.serving.backends.jax_exec import MLA_GEOMETRIES
+        geom = dict(cfg=MLA_GEOMETRIES[args.mla], dtype=jnp.dtype(args.dtype))
+        backend = (JaxExecBackend(**geom) if args.backend == "exec" else
+                   ShardMapExecBackend(fused=not args.serial_exec, **geom))
     else:
         backend = None
     return ServingEngine(
@@ -218,7 +254,8 @@ def build_trace(args, eng: ServingEngine, replay=None):
 
 def main(argv=None) -> None:
     args = build_parser().parse_args(argv)
-    if args.verify and args.backend not in ("exec", "shard_map"):
+    if args.verify is not None and args.backend not in ("exec",
+                                                        "shard_map"):
         raise SystemExit("--verify checks exec outputs against the §3.3 "
                          "oracle: it requires --backend exec or shard_map")
     if args.trace and args.save_trace:
@@ -242,8 +279,17 @@ def main(argv=None) -> None:
         sel_meta, _ = load_selection_trace(args.selection_trace)
         apply_trace_meta(args, sel_meta, keys=SELECTION_META_ARGS,
                          source="--selection-trace")
+    if args.backend != "analytic" or args.selection:
+        enable_compile_cache()
     eng = build_engine(args)
     steps = build_trace(args, eng, replay)
+    tol = None
+    if args.verify is not None:
+        from repro.serving.backends.jax_exec import oracle_tolerance
+        # a bare --verify takes the dtype's tolerance
+        tol = (oracle_tolerance(args.dtype) if args.verify is True
+               else args.verify)
+    over_tol = []
 
     # reporting trails accounting: at --pipeline-depth >= 2 a scheduled
     # step may still be in flight when the loop moves on, so per-step
@@ -262,9 +308,12 @@ def main(argv=None) -> None:
                     f"makespan {s.latency_s*1e6:.0f}us")
             if eng.selector is not None:
                 line += f", {s.n_selected} selected pairs"
-            if args.verify:
+            if tol is not None:
                 from repro.serving.backends.jax_exec import max_oracle_err
-                line += f", max|err| {max_oracle_err(eng, reqs, s.step):.2e}"
+                err = max_oracle_err(eng, reqs, s.step)
+                if not err <= tol:
+                    over_tol.append((s.step, err))
+                line += f", max|err| {err:.2e}"
             print(line)
             report = eng.measured_reports[reported[0]]
             if report is not None:
@@ -285,6 +334,11 @@ def main(argv=None) -> None:
         print(f"[serve] pipeline: depth {depth}, planner overlap hidden "
               f"{eng.planner_overlap_s*1e3:.2f}ms, "
               f"{eng.misspeculation_replans} replans")
+    if over_tol:
+        raise SystemExit(
+            f"[serve] --verify FAILED: {len(over_tol)} step(s) past "
+            f"max|err| {tol:g}: "
+            + ", ".join(f"step {st} {err:.2e}" for st, err in over_tol))
 
     if args.save_selection_trace:
         from repro.serving.selection import save_selection_trace

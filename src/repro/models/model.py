@@ -22,7 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro import compat
 from repro.distributed import policy as POL
 from repro.models import attention as A
 from repro.models import layers as L
@@ -134,7 +133,7 @@ def _moe_call(p_moe, cfg: ModelConfig, x, ep_axis=None):
             aux = jax.lax.pmean(aux, dp)
         return y, aux
 
-    y, aux = compat.shard_map(
+    y, aux = jax.shard_map(
         f, mesh=mesh, in_specs=(p_specs, x_spec),
         out_specs=(x_spec, P()))(p_moe, x)
     return y, aux

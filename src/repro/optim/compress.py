@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import compat
-
 
 def quantize(g: jax.Array) -> Tuple[jax.Array, jax.Array]:
     scale = jnp.max(jnp.abs(g)) / 127.0 + 1e-12
@@ -37,7 +35,7 @@ def compressed_psum_with_feedback(grads, errors, axis: str):
 
     grads/errors: matching pytrees (f32). Returns (mean-reduced grads,
     new errors)."""
-    n = compat.axis_size(axis)
+    n = lax.axis_size(axis)
 
     def one(g, e):
         g = g.astype(jnp.float32) + e

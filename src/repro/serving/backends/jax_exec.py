@@ -69,6 +69,34 @@ if TYPE_CHECKING:                                    # pragma: no cover
 TINY_MLA = MLAConfig(d_model=64, n_heads=2, kv_lora_rank=16,
                      qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8)
 
+# DeepSeek-V2's published attention widths (config.json of
+# deepseek-ai/DeepSeek-V2): 128 heads, kv_lora_rank 512, rope 64, nope 128,
+# v_head 128 — so d_qk = 576 and d_v = 512, the paper's wire payload.
+DEEPSEEK_V2_MLA = MLAConfig(d_model=5120, n_heads=128, kv_lora_rank=512,
+                            q_lora_rank=1536, qk_nope_head_dim=128,
+                            qk_rope_head_dim=64, v_head_dim=128)
+
+# execution geometries a caller can name (serve --mla, chip_smoke.py)
+MLA_GEOMETRIES = {"tiny": TINY_MLA, "deepseek-v2": DEEPSEEK_V2_MLA}
+
+
+def oracle_tolerance(dtype) -> float:
+    """Largest max|exec - oracle| a correct step may show in `dtype`.
+
+    float32: the exec path and the oracle differ only in the order of f32
+    sums, ~1e-6 at unit scale; 1e-4 leaves room for long contractions.
+
+    bfloat16: both sides feed the MXU the same bf16 cache and queries and
+    accumulate in f32, but the TPU's default matmul precision rounds the
+    f32 softmax weights of the weights @ values dot to bf16, a relative
+    error of at most 2^-9 per weight. The exec path normalises weights
+    per chunk and the oracle over all of a request's chunks, so the two
+    round differently, and an output element can differ by up to
+    2^-8 * sum_i p_i |v_i|: 2^-8 times a softmax-weighted mean of |v|,
+    which for unit-normal cache entries stays below 4. So 2^-6. Losing
+    one of a request's two 2048-token chunks moves some output by ~1."""
+    return 2.0 ** -6 if jnp.dtype(dtype) == jnp.bfloat16 else 1e-4
+
 
 def _stable_seed(*parts) -> int:
     """Deterministic 32-bit seed from stringable parts (NOT Python hash(),

@@ -61,7 +61,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core.chunk_store import ChunkStore
 from repro.core.merge import NEG_INF, Partial, merge_stacked, merge_tree
 from repro.core.routing import (check_route_shards, fanout_exchange,
@@ -322,9 +321,8 @@ class ShardMapExecBackend(JaxExecBackend):
                    for b in self._pool.values())
 
     def _shmap(self, body, in_specs, out_specs):
-        return jax.jit(compat.shard_map(body, mesh=self.mesh,
-                                        in_specs=in_specs,
-                                        out_specs=out_specs))
+        return jax.jit(jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
+                                     out_specs=out_specs))
 
     def _staged(self, statics: Tuple, build, args) -> Tuple[Any, float]:
         key = statics + tuple(
@@ -762,15 +760,31 @@ class ShardMapExecBackend(JaxExecBackend):
         compute once. The skipped value is bitwise what the masked
         compute produces on a zero shard (all-False valid -> -inf
         logits): the merge identity, so fanout merge_stacked semantics
-        are unchanged."""
+        are unchanged. The identity is built device-invariant; it is cast
+        to varying over AXIS so both cond branches carry the computed
+        branch's type."""
         aval = jax.eval_shape(
             lambda a, b, d: absorbed_partial(self.cfg, a, b, d), q, c, v)
         ident = Partial(o=jnp.zeros(aval.o.shape, aval.o.dtype),
                         m=jnp.full(aval.m.shape, NEG_INF, aval.m.dtype),
                         l=jnp.zeros(aval.l.shape, aval.l.dtype))
+        ident = jax.tree.map(lambda x: lax.pcast(x, AXIS, to="varying"),
+                             ident)
         return lax.cond(lax.axis_index(AXIS) == holder,
                         lambda: absorbed_partial(self.cfg, q, c, v),
                         lambda: ident)
+
+    def _route_pair_program(self, holder: int, requester: int):
+        """The fused one-home ROUTE program: ship the stacked queries
+        requester -> holder, attend on the holder shard, return the
+        partial. Arguments (q, c, v) are P(AXIS)-sharded over self.mesh."""
+        PS = P(AXIS)
+
+        def body(q, c, v):
+            qh = pairwise_ship(q, holder, requester, AXIS)
+            p = self._gated_partial(holder, qh, c, v)
+            return pairwise_return(p, holder, requester, AXIS)
+        return self._shmap(body, (PS, PS, PS), Partial(o=PS, m=PS, l=PS))
 
     @staticmethod
     def _record_resources(rec) -> List:
@@ -962,15 +976,11 @@ class ShardMapExecBackend(JaxExecBackend):
                            self.dtype)
 
             def launch(bufs):
-                def build():
-                    def body(q, c, v):
-                        qh = pairwise_ship(q, holder, requester, AXIS)
-                        p = self._gated_partial(holder, qh, c, v)
-                        return pairwise_return(p, holder, requester, AXIS)
-                    return self._shmap(body, (PS, PS, PS), PART)
                 args = (bufs[qg], bufs[cg], bufs[vg])
-                fn = self._fused_fn(("route-pair", holder, requester),
-                                    build, args)
+                fn = self._fused_fn(
+                    ("route-pair", holder, requester),
+                    lambda: self._route_pair_program(holder, requester),
+                    args)
                 t_launch = time.perf_counter()
                 return t_launch, fn(*args)
 

@@ -266,7 +266,6 @@ class ShardMapIndexerService(IndexerService):
         from jax import lax
         from jax.sharding import PartitionSpec as P
 
-        from repro import compat
         from repro.serving.backends import shard_map as SM
 
         keys = self.ensure_index_keys(store, chunk_id)
@@ -285,9 +284,8 @@ class ShardMapIndexerService(IndexerService):
                 all_iq = lax.all_gather(iq_l, SM.AXIS)    # (NI, m_q, d)
                 scores = jnp.einsum("md,sd->ms", all_iq[home], keys_l)
                 return scores.max(axis=0)                 # (S,) pooled
-            return jax.jit(compat.shard_map(body, mesh=mesh,
-                                            in_specs=(PS, PS),
-                                            out_specs=PS))
+            return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(PS, PS),
+                                         out_specs=PS))
 
         cache_key = ("pooled", home, holder,
                      tuple(iq32.shape), tuple(keys.shape))

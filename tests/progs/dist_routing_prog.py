@@ -18,11 +18,11 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core.merge import Partial
 from repro.core.routing import (route_fanout, route_pairwise,
                                 route_pairwise_tpla, route_ring)
 from repro.distributed.hlo_analysis import parse_collectives
+from repro.launch.mesh import make_mesh
 from repro.models import mla as M
 from repro.models.module import KeyGen, split
 
@@ -50,7 +50,7 @@ def build_inputs(seed=0):
 
 
 def test_fanout_and_ring():
-    mesh = jax.make_mesh((NI,), ("instance",))
+    mesh = make_mesh((NI,), ("instance",))
     q_abs, ckv = build_inputs()
     valid = jnp.ones(S, bool)
 
@@ -63,7 +63,7 @@ def test_fanout_and_ring():
     specs = (P("instance"), P("instance"), P("instance"))
     out_specs = Partial(o=P("instance"), m=P("instance"), l=P("instance"))
     for name, fn in (("fanout", fan), ("ring", ring)):
-        shmapped = jax.jit(compat.shard_map(fn, mesh=mesh, in_specs=specs,
+        shmapped = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=specs,
                                          out_specs=out_specs))
         got = shmapped(q_abs, ckv, valid)
         want = M.absorbed_partial(CFG, q_abs, ckv)
@@ -78,7 +78,7 @@ def test_fanout_and_ring():
     owner = rng.randint(0, NI, S)
     valid_scattered = jnp.asarray(
         (owner == (np.arange(S) // S_LOCAL)))   # each owns subset of own range
-    shmapped = jax.jit(compat.shard_map(fan, mesh=mesh, in_specs=specs,
+    shmapped = jax.jit(jax.shard_map(fan, mesh=mesh, in_specs=specs,
                                      out_specs=out_specs))
     got = shmapped(q_abs, ckv, valid_scattered)
     want = M.absorbed_partial(CFG, q_abs, ckv,
@@ -89,7 +89,7 @@ def test_fanout_and_ring():
 
 
 def test_pairwise():
-    mesh = jax.make_mesh((NI,), ("instance",))
+    mesh = make_mesh((NI,), ("instance",))
     q_abs, ckv = build_inputs(seed=7)
     requester, holder = 0, 3
 
@@ -100,7 +100,7 @@ def test_pairwise():
                               requester=requester, axis="instance")
 
     out_specs = Partial(o=P("instance"), m=P("instance"), l=P("instance"))
-    shmapped = jax.jit(compat.shard_map(pw, mesh=mesh,
+    shmapped = jax.jit(jax.shard_map(pw, mesh=mesh,
                                      in_specs=(P("instance"), P("instance")),
                                      out_specs=out_specs))
     got = shmapped(q_abs, ckv)
@@ -117,7 +117,7 @@ def test_pairwise():
 
 def test_tpla_rank_pairing():
     NTP = 4
-    mesh = jax.make_mesh((2, NTP), ("instance", "tp"))
+    mesh = make_mesh((2, NTP), ("instance", "tp"))
     q_abs, ckv = build_inputs(seed=11)
     q_abs = q_abs[: 2 * B]
     holder_cache = ckv[:S_LOCAL]
@@ -142,7 +142,7 @@ def test_tpla_rank_pairing():
                                    instance_axis="instance", tp_axis="tp")
         return part.o[None, None], part.m[None, None], part.l[None, None]
 
-    fn = jax.jit(compat.shard_map(
+    fn = jax.jit(jax.shard_map(
         tpla, mesh=mesh,
         in_specs=(P("instance", "tp"), P("instance", "tp")),
         out_specs=(P("instance", "tp", None, None, None),
@@ -163,7 +163,7 @@ def test_tpla_rank_pairing():
     cp_tpla = parse_collectives(hlo_tpla).result_bytes.get(
         "collective-permute", 0)
 
-    mesh1 = jax.make_mesh((2, NTP), ("instance", "tp"))
+    mesh1 = make_mesh((2, NTP), ("instance", "tp"))
     def plain(q, c):
         q, c = q[0, 0], c[0, 0]
         part = route_pairwise(CFG, q, c,
@@ -174,7 +174,7 @@ def test_tpla_rank_pairing():
                              (2, NTP) + q_abs[:B].shape)
     c_rep = jnp.broadcast_to(holder_cache[None, None],
                              (2, NTP) + holder_cache.shape)
-    fn2 = jax.jit(compat.shard_map(
+    fn2 = jax.jit(jax.shard_map(
         plain, mesh=mesh1,
         in_specs=(P("instance", "tp"), P("instance", "tp")),
         out_specs=(P("instance", "tp", None, None, None),

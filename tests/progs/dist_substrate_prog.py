@@ -18,25 +18,21 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.checkpoint.manager import CheckpointManager
 from repro.distributed.hlo_costs import analyse_hlo
+from repro.launch.mesh import make_mesh
 from repro.optim.compress import compressed_psum_with_feedback
 
 
-def mk_mesh(shape, axes):
-    return compat.make_mesh(shape, axes)
-
-
 def test_elastic_checkpoint():
-    mesh_a = mk_mesh((8,), ("data",))
+    mesh_a = make_mesh((8,), ("data",))
     w = jnp.arange(64 * 32, dtype=jnp.float32).reshape(64, 32)
     w_a = jax.device_put(w, NamedSharding(mesh_a, P("data", None)))
     with tempfile.TemporaryDirectory() as d:
         cm = CheckpointManager(d)
         cm.save(1, {"w": w_a}, blocking=True)
         # "rescale": restore on a DIFFERENT topology + sharding
-        mesh_b = mk_mesh((4, 2), ("data", "model"))
+        mesh_b = make_mesh((4, 2), ("data", "model"))
         sh = {"w": NamedSharding(mesh_b, P("data", "model"))}
         back = cm.restore(1, {"w": w_a}, shardings=sh)
         np.testing.assert_array_equal(np.asarray(back["w"]), np.asarray(w))
@@ -45,7 +41,7 @@ def test_elastic_checkpoint():
 
 
 def test_compressed_dp_parity():
-    mesh = mk_mesh((8,), ("pod",))
+    mesh = make_mesh((8,), ("pod",))
     # toy regression model, data sharded over 'pod'
     key = jax.random.PRNGKey(0)
     X = jax.random.normal(key, (64, 16))
@@ -63,9 +59,7 @@ def test_compressed_dp_parity():
             else:
                 g = lax.pmean(g, "pod")
             return w - 0.05 * g, e
-        # unchecked: old jax cannot statically infer that the error-feedback
-        # state stays replicated through the quantize/dequantize ops
-        return jax.jit(compat.shard_map_unchecked(
+        return jax.jit(jax.shard_map(
             step, mesh=mesh,
             in_specs=(P(), P(), P("pod"), P("pod")),
             out_specs=(P(), P())))
@@ -98,13 +92,13 @@ def test_collective_matmul_overlap():
     the barrier all-gather matmul == the dense reference (DESIGN.md §5)."""
     from repro.distributed.collective_matmul import (
         allgather_matmul_barrier, allgather_matmul_overlapped)
-    mesh = mk_mesh((8,), ("tp",))
+    mesh = make_mesh((8,), ("tp",))
     m, d, n = 32, 16, 64
     x = jax.random.normal(jax.random.PRNGKey(2), (m, d))
     w = jax.random.normal(jax.random.PRNGKey(3), (d, n))
 
     for fn in (allgather_matmul_overlapped, allgather_matmul_barrier):
-        sm = jax.jit(compat.shard_map(
+        sm = jax.jit(jax.shard_map(
             lambda xs, wb: fn(xs, wb, "tp"), mesh=mesh,
             in_specs=(P("tp", None), P(None, "tp")),
             out_specs=P("tp", None)))
@@ -112,7 +106,7 @@ def test_collective_matmul_overlap():
         np.testing.assert_allclose(np.asarray(got), np.asarray(x @ w),
                                    rtol=2e-5, atol=2e-5)
     # the overlapped form uses ppermute (pipelined), not one big all-gather
-    sm_o = jax.jit(compat.shard_map(
+    sm_o = jax.jit(jax.shard_map(
         lambda xs, wb: allgather_matmul_overlapped(xs, wb, "tp"), mesh=mesh,
         in_specs=(P("tp", None), P(None, "tp")), out_specs=P("tp", None)))
     txt = sm_o.lower(x, w).compile().as_text()

@@ -392,6 +392,7 @@ class TestFabricTables:
 # Serve CLI: one saved trace drives both backends (satellite).
 # ---------------------------------------------------------------------------
 
+@pytest.mark.usefixtures("compile_cache_dir")
 class TestServeCLI:
     ARGS = ["--instances", "4", "--pods", "2", "--chunks", "6",
             "--chunk-tokens", "64", "--agents", "6", "--steps", "3"]
@@ -455,6 +456,58 @@ class TestServeCLI:
             serve.main(self.ARGS + ["--trace", str(tmp_path / "a.json"),
                                     "--save-trace",
                                     str(tmp_path / "b.json")])
+
+    def test_verify_exits_nonzero_past_tolerance(self, capsys):
+        """--verify TOL fails the run when a step's max|err| exceeds TOL;
+        the dtype's own tolerance passes the same run."""
+        from repro.launch import serve
+        run = self.ARGS + ["--backend", "exec", "--verify"]
+        serve.main(run)
+        assert "max|err|" in capsys.readouterr().out
+        with pytest.raises(SystemExit, match="--verify FAILED"):
+            serve.main(run + ["0"])
+
+    def test_named_geometry_and_dtype(self):
+        import jax.numpy as jnp
+        from repro.launch import serve
+        from repro.serving.backends.jax_exec import (DEEPSEEK_V2_MLA,
+                                                     TINY_MLA)
+        args = serve.build_parser().parse_args(self.ARGS + [
+            "--backend", "exec", "--mla", "deepseek-v2",
+            "--dtype", "bfloat16"])
+        backend = serve.build_engine(args).backend
+        assert backend.cfg == DEEPSEEK_V2_MLA and backend.dtype == jnp.bfloat16
+        assert (DEEPSEEK_V2_MLA.d_qk, DEEPSEEK_V2_MLA.kv_lora_rank) == (576,
+                                                                        512)
+        args = serve.build_parser().parse_args(self.ARGS + ["--backend",
+                                                            "exec"])
+        backend = serve.build_engine(args).backend
+        assert backend.cfg == TINY_MLA and backend.dtype == jnp.float32
+
+    def test_compile_cache_lives_where_the_env_says(self, compile_cache_dir):
+        """With JAX_COMPILATION_CACHE_DIR set, entries land there and the
+        checkout's default cache directory is left alone."""
+        import pathlib
+        from repro.launch import serve
+        default = pathlib.Path(serve.__file__).resolve().parents[3] / \
+            ".jax_cache"
+        before = set(default.iterdir()) if default.exists() else set()
+        # a chunk length no other test uses, so this process compiles anew
+        serve.main(self.ARGS + ["--backend", "exec", "--steps", "1",
+                                "--chunk-tokens", "40"])
+        assert any(compile_cache_dir.iterdir())
+        after = set(default.iterdir()) if default.exists() else set()
+        assert after == before
+
+    def test_compile_cache_defaults_to_checkout(self, monkeypatch):
+        import pathlib
+        import jax
+        from repro.launch import serve
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = serve.enable_compile_cache()
+        root = pathlib.Path(serve.__file__).resolve().parents[3]
+        assert path == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
 
 
 # ---------------------------------------------------------------------------

@@ -6,8 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.distributed.hlo_costs import analyse_hlo, split_computations
+from repro.launch.mesh import make_mesh
 
 
 def _body(x, w):
@@ -38,17 +38,17 @@ class TestHloCosts:
     def test_matches_xla_on_unrolled(self, compiled_pair):
         _, cu = compiled_pair
         ours = analyse_hlo(cu.as_text()).flops
-        xla = compat.cost_analysis(cu)["flops"]
+        xla = cu.cost_analysis()["flops"]
         assert ours == pytest.approx(xla, rel=0.01)
 
     def test_scan_trip_count_correction(self, compiled_pair):
         cs, cu = compiled_pair
         ours_scan = analyse_hlo(cs.as_text()).flops
-        xla_unrolled = compat.cost_analysis(cu)["flops"]
+        xla_unrolled = cu.cost_analysis()["flops"]
         # corrected scan flops == unrolled flops (8 matmuls)
         assert ours_scan == pytest.approx(xla_unrolled, rel=0.01)
         # and XLA's own number on the scanned version is ~8x too small
-        assert compat.cost_analysis(cs)["flops"] == pytest.approx(
+        assert cs.cost_analysis()["flops"] == pytest.approx(
             xla_unrolled / 8, rel=0.01)
 
     def test_nested_scan(self):
@@ -72,7 +72,7 @@ class TestHloCosts:
         assert flops == pytest.approx(expect, rel=0.05)
 
     def test_collectives_scaled_by_trips(self):
-        mesh = compat.make_mesh((1,), ("x",))
+        mesh = make_mesh((1,), ("x",))
 
         def f(xs):
             def step(c, x):
@@ -80,7 +80,7 @@ class TestHloCosts:
             y, _ = jax.lax.scan(step, jnp.zeros((16,)), xs)
             return y
 
-        sm = jax.jit(compat.shard_map_unchecked(
+        sm = jax.jit(jax.shard_map(
             f, mesh=mesh,
             in_specs=jax.sharding.PartitionSpec("x"),
             out_specs=jax.sharding.PartitionSpec()))

@@ -215,3 +215,18 @@ class TestFlashPrefill:
         for o in outs[1:]:
             np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(o),
                                        atol=2e-6, rtol=1e-5)
+
+
+class TestInterpretSelection:
+    @pytest.mark.parametrize("platform,want", [("cpu", True),
+                                               ("tpu", False)])
+    def test_cpu_interprets_tpu_compiles(self, monkeypatch, platform, want):
+        from repro.kernels import common
+        monkeypatch.setattr(common.jax, "default_backend", lambda: platform)
+        assert common.use_interpret() is want
+
+    def test_other_platform_raises(self, monkeypatch):
+        from repro.kernels import common
+        monkeypatch.setattr(common.jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            common.use_interpret()

@@ -426,6 +426,7 @@ class TestSelectionCosts:
 # Serve CLI: the selection flags.
 # ---------------------------------------------------------------------------
 
+@pytest.mark.usefixtures("compile_cache_dir")
 class TestServeSelectionCLI:
     WORLD = ["--instances", "4", "--pods", "2", "--chunks", "6",
              "--chunk-tokens", "128", "--agents", "6", "--steps", "3"]
