@@ -1,8 +1,9 @@
 """Where the fused execution path's wall time goes (ISSUE 8).
 
 Runs the frozen mixed_congested trace through the fused/overlapped
-shard_map backend and reads the backend's own phase accumulator
-(`phase_wall_total`) — the four phases of `_execute_overlapped`:
+shard_map backend and sums the phase timer's per-step self seconds
+(`StepStats.phase_s`, repro.obs.phases) — the four phases of the fused
+path, plus the `query` phases of the queries it materialises:
 
   * stack    — host-side shard assembly + the ONE batched device_put
                per step (`_StackBatch.commit`);
@@ -47,8 +48,8 @@ def profile(repetitions: int, serial: bool = False,
 
     backend = ShardMapExecBackend(fused=not serial)
     per_rep, overlap = [], []
-    plan_wall, barrier0 = 0.0, sum(
-        v for k, v in backend.phase_wall_total.items() if k == "barrier")
+    plan_wall = 0.0
+    split: dict = {}
     for _ in range(repetitions):
         eng, steps = SCENARIOS["mixed_congested"](
             backend, cfg=EngineConfig(pipeline_depth=pipeline_depth))
@@ -57,14 +58,16 @@ def profile(repetitions: int, serial: bool = False,
         per_rep.append(time.perf_counter() - t0)
         overlap.append(eng.planner_overlap_s)
         plan_wall += sum(eng.plan_walls)
-    return {"reps": per_rep, "split": dict(backend.phase_wall_total),
-            "last_step_split": dict(backend.phase_wall),
+        for st in eng.stats:
+            for k, v in st.phase_s.items():
+                split[k] = split.get(k, 0.0) + v
+    return {"reps": per_rep, "split": split,
+            "last_step_split": dict(eng.stats[-1].phase_s),
             "mode": "serial" if serial else "fused",
             "pipeline_depth": pipeline_depth,
             "plan_wall_s": plan_wall,
             "overlap_per_rep": overlap,
-            "device_wall_s": backend.phase_wall_total.get("barrier", 0.0)
-            - barrier0}
+            "device_wall_s": split.get("barrier", 0.0)}
 
 
 def main() -> None:
@@ -85,9 +88,9 @@ def main() -> None:
           + " ".join(f"{1000 * t:.1f}ms" for t in out["reps"])
           + " (rep 0 cold: compiles land there)")
     total = sum(out["split"].values())
-    if not out["split"]:
+    if "barrier" not in out["split"]:
         print("  (no phase split recorded — serial mode bypasses "
-              "_execute_overlapped)")
+              "the fused path)")
         return
     print(f"phase split over all reps ({1000 * total:.1f} ms attributed):")
     for name, v in sorted(out["split"].items(), key=lambda kv: -kv[1]):

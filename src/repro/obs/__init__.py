@@ -18,6 +18,11 @@ keeps plain-int cache counters (free either way) and hands everything to
 ``obs is not NULL_OBS`` check in ``schedule_step``. A run constructed
 without an Obs pays one identity comparison per step — that is the
 "disabled tracer costs near-zero" guarantee the planner bench guards.
+
+Apart from the bundle, :mod:`repro.obs.phases` is always on: the engine
+and the execution backends time the step's phases with it, into
+``StepStats.phase_s`` / ``phase_n``, and each phase is a
+``jax.profiler`` span wherever a capture is running.
 """
 
 from __future__ import annotations
@@ -169,16 +174,9 @@ class Obs:
         m.gauge("store.promotions").set(store.promotions)
 
         # -- backend telemetry ------------------------------------------------
-        backend = engine.backend
-        qh = getattr(backend, "qmemo_hits", None)
-        if qh is not None:
-            m.gauge("exec.query_memo.hit").set(qh)
-            m.gauge("exec.query_memo.miss").set(
-                getattr(backend, "qmemo_misses", 0))
-        phase_total = getattr(backend, "phase_wall_total", None)
-        if phase_total:
-            for phase, secs in phase_total.items():
-                m.gauge("exec.phase_wall_s", phase=phase).set(secs)
+        for phase, secs in stats.phase_s.items():
+            g = m.gauge("exec.phase_wall_s", phase=phase)
+            g.set(g.value + secs)
         if report is not None:
             if report.stage_fills:
                 # satellite (ISSUE 9): stage-measurement gaps per-run, not
@@ -220,7 +218,7 @@ class Obs:
                                       step=stats.step)
                 self.tracer.wall_span("execute", t1, t2, track=track,
                                       step=stats.step,
-                                      backend=type(backend).__name__)
+                                      backend=type(engine.backend).__name__)
                 self.tracer.wall_span("account", t2, t3, track=track,
                                       step=stats.step)
             self.tracer.add_step(

@@ -55,6 +55,7 @@ from repro.core.merge import Partial, merge_tree
 from repro.core.routing import route_batched
 from repro.core.splice import splice_delta_rotate
 from repro.models.mla import MLAConfig, absorbed_partial
+from repro.obs import phases
 from repro.serving.backends.base import StepExecution, StepTicket
 from repro.serving.plan import Request, StepPlan, build_timeline
 
@@ -205,12 +206,10 @@ class JaxExecBackend:
         # previous step are pruned when a new step arrives.
         self._qmemo: Dict[Tuple[int, int, int], jax.Array] = {}
         self._qmemo_step = -1
-        # query-memo effectiveness (ISSUE 9), read by the obs registry
-        self.qmemo_hits = 0
-        self.qmemo_misses = 0
 
     def query_of(self, rq: Request, step: int) -> jax.Array:
-        """Memoized query_for: the request's decode queries this step."""
+        """Memoized query_for: the request's decode queries this step. A
+        miss is one `query` phase."""
         if step != self._qmemo_step:
             if step > self._qmemo_step:
                 self._qmemo = {k: v for k, v in self._qmemo.items()
@@ -222,10 +221,9 @@ class JaxExecBackend:
         key = (seed, step, rq.m_q)
         q = self._qmemo.get(key)
         if q is None:
-            self.qmemo_misses += 1
-            q = self._qmemo[key] = query_for(self.cfg, rq, step, self.dtype)
-        else:
-            self.qmemo_hits += 1
+            with phases.phase("query", step, req=rq.req_id):
+                q = self._qmemo[key] = query_for(self.cfg, rq, step,
+                                                 self.dtype)
         return q
 
     # -- materialization ----------------------------------------------------
@@ -270,34 +268,43 @@ class JaxExecBackend:
             return jnp.asarray(np.asarray(sel.masks[chunk_id]))
 
         parts: Dict[int, List[Partial]] = defaultdict(list)
+        step = plan.step
 
         # resident accesses: local attention on the instance's copy,
         # through the selection mask when the indexer chose for this request
         for rp in plan.resident_pairs:
-            arr = self._array_on(store, rp.chunk_id, rp.instance)
-            parts[rp.req_id].append(
-                absorbed_partial(self.cfg, q_of(rp.req_id), arr,
-                                 mask_of(rp.req_id, rp.chunk_id)))
+            with phases.phase("resident", step, req=rp.req_id,
+                              holder=rp.instance):
+                arr = self._array_on(store, rp.chunk_id, rp.instance)
+                parts[rp.req_id].append(
+                    absorbed_partial(self.cfg, q_of(rp.req_id), arr,
+                                     mask_of(rp.req_id, rp.chunk_id)))
 
         for rec in plan.records:
             if rec.backup or not rec.req_ids:
                 continue
             if rec.primitive == "route":
-                self._exec_route(store, rec, q_of, parts, mask_of)
+                with phases.phase("route", step, holder=rec.holder):
+                    self._exec_route(store, rec, q_of, parts, mask_of)
             elif rec.primitive in ("fetch", "fetch_replica"):
-                if rec.req_ids[0] in sels:
-                    self._exec_fetch_selected(store, rec, q_of, parts,
-                                              sels[rec.req_ids[0]])
-                else:
-                    self._exec_fetch(store, rec, q_of, parts)
+                with phases.phase("fetch", step, holder=rec.holder):
+                    if rec.req_ids[0] in sels:
+                        self._exec_fetch_selected(store, rec, q_of, parts,
+                                                  sels[rec.req_ids[0]])
+                    else:
+                        self._exec_fetch(store, rec, q_of, parts)
             else:                                     # local re-prefill
-                arr = self.ensure_chunk_data(store, rec.chunk_id)
-                for rid in rec.req_ids:
-                    parts[rid].append(
-                        absorbed_partial(self.cfg, q_of(rid), arr,
-                                         mask_of(rid, rec.chunk_id)))
+                with phases.phase("local", step, holder=rec.holder):
+                    arr = self.ensure_chunk_data(store, rec.chunk_id)
+                    for rid in rec.req_ids:
+                        parts[rid].append(
+                            absorbed_partial(self.cfg, q_of(rid), arr,
+                                             mask_of(rid, rec.chunk_id)))
 
-        outputs = {rid: merge_tree(ps) for rid, ps in parts.items()}
+        outputs = {}
+        for rid, ps in parts.items():
+            with phases.phase("merge", step, req=rid):
+                outputs[rid] = merge_tree(ps)
         return StepExecution(timeline=build_timeline(plan.records),
                              outputs=outputs, backend=self.name)
 

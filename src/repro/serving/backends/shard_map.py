@@ -68,6 +68,7 @@ from repro.core.routing import (check_route_shards, fanout_exchange,
 from repro.core.splice import (fetch_chunk, fetch_scattered_gather,
                                splice_delta_rotate)
 from repro.models.mla import MLAConfig, absorbed_partial
+from repro.obs import phases
 from repro.serving import timeline as TL
 from repro.serving.backends.base import StepExecution, StepTicket
 from repro.serving.backends.jax_exec import (JaxExecBackend, TINY_MLA,
@@ -288,12 +289,6 @@ class ShardMapExecBackend(JaxExecBackend):
         self._tiny = None
         self._listening_store = None
         self._fill_count = 0
-        # per-step / cumulative phase walls of the fused path (stack /
-        # dispatch / barrier / merge) — benchmarks/profile_exec.py reads
-        # these; four perf_counter probes per step, nothing on the
-        # per-record path
-        self.phase_wall: Dict[str, float] = {}
-        self.phase_wall_total: Dict[str, float] = {}
 
     # -- mesh binding -------------------------------------------------------
 
@@ -862,39 +857,37 @@ class ShardMapExecBackend(JaxExecBackend):
 
         # -- STACK: collect every record's device inputs, ship them in
         # ONE batched transfer ---------------------------------------------
-        t0 = time.perf_counter()
-        batch = self._asm.begin_batch()
-        preps = []
-        for i, rec in enumerate(plan.records):
-            if rec.backup or not rec.req_ids:
-                continue
-            if rec.primitive == "route":
-                prep = self._prep_route(store, rec, q_of, reqs, mask_of,
-                                        batch)
-            elif rec.primitive in ("fetch", "fetch_replica"):
-                if rec.req_ids[0] in sels:
-                    prep = self._prep_fetch_selected(
-                        store, rec, q_of, batch, sels[rec.req_ids[0]])
+        with phases.phase("stack", plan.step):
+            batch = self._asm.begin_batch()
+            preps = []
+            for i, rec in enumerate(plan.records):
+                if rec.backup or not rec.req_ids:
+                    continue
+                if rec.primitive == "route":
+                    prep = self._prep_route(store, rec, q_of, reqs,
+                                            mask_of, batch)
+                elif rec.primitive in ("fetch", "fetch_replica"):
+                    if rec.req_ids[0] in sels:
+                        prep = self._prep_fetch_selected(
+                            store, rec, q_of, batch, sels[rec.req_ids[0]])
+                    else:
+                        prep = self._prep_fetch(store, rec, q_of, reqs,
+                                                batch)
                 else:
-                    prep = self._prep_fetch(store, rec, q_of, reqs, batch)
-            else:
-                prep = self._prep_local(store, rec, q_of, reqs, mask_of,
-                                        batch)
-            preps.append((i, rec, prep))
-        bufs = batch.commit()
-        t_stack = time.perf_counter() - t0
+                    prep = self._prep_local(store, rec, q_of, reqs,
+                                            mask_of, batch)
+                preps.append((i, rec, prep))
+            bufs = batch.commit()
 
         # -- DISPATCH: launch every group's fused program in record order
         # with NO intermediate block — JAX's async dispatch pipelines the
         # launches exactly the way the overlap timeline models ---------------
-        t0 = time.perf_counter()
         tasks = []
-        for i, rec, (launch, post) in preps:
-            t_launch, out = launch(bufs)
-            tasks.append([i, rec, out, post, t_launch, 0.0])
-        t_dispatch = time.perf_counter() - t0
-        return {"parts": parts, "tasks": tasks, "t_wall0": t_wall0,
-                "t_stack": t_stack, "t_dispatch": t_dispatch}
+        with phases.phase("dispatch", plan.step):
+            for i, rec, (launch, post) in preps:
+                t_launch, out = launch(bufs)
+                tasks.append([i, rec, out, post, t_launch, 0.0])
+        return {"parts": parts, "tasks": tasks, "t_wall0": t_wall0}
 
     def _await_overlapped(self, engine: "ServingEngine", plan: StepPlan,
                           state: dict) -> StepExecution:
@@ -907,41 +900,34 @@ class ShardMapExecBackend(JaxExecBackend):
         self._fill_count = 0
 
         # -- BARRIER: block once per step, in launch order -------------------
-        t0 = time.perf_counter()
-        for task in tasks:
-            jax.block_until_ready(task[2])
-            task[5] = time.perf_counter()
-        t_barrier = time.perf_counter() - t0
+        with phases.phase("barrier", plan.step):
+            for task in tasks:
+                jax.block_until_ready(task[2])
+                task[5] = time.perf_counter()
 
         # -- MERGE/account: attribute walls net of same-resource queueing,
         # apportion over planned stage ratios, splice partials per request,
         # persist replicas (the only host round-trip left) -------------------
-        t0 = time.perf_counter()
-        sel_times = getattr(engine.selector, "measured_index_s",
-                            None) or {}
-        measured_flows: List[TL.Flow] = []
-        last_done: Dict[Any, float] = {}
-        for i, rec, out, post, t_launch, t_done in tasks:
-            resources = self._record_resources(rec)
-            t_ready = max([t_launch]
-                          + [last_done.get(r, 0.0) for r in resources])
-            wall = max(t_done - t_ready, 1e-9)
-            for r in resources:
-                last_done[r] = max(last_done.get(r, 0.0), t_done)
-            if rec.stages:
-                meas = self._apportion(rec, wall, sel_times, plan.step)
-                measured_flows.append(self._measured_flow(rec, i, meas))
-            post(out, parts)
-        outputs = {rid: merge_tree(ps) for rid, ps in parts.items()}
-        analytic = self._analytic_timeline(plan)
-        report = self._report(plan, analytic, measured_flows, t_wall0,
-                              "fused")
-        self.phase_wall = {"stack": state["t_stack"],
-                           "dispatch": state["t_dispatch"],
-                           "barrier": t_barrier,
-                           "merge": time.perf_counter() - t0}
-        for k, v in self.phase_wall.items():
-            self.phase_wall_total[k] = self.phase_wall_total.get(k, 0.0) + v
+        with phases.phase("merge", plan.step):
+            sel_times = getattr(engine.selector, "measured_index_s",
+                                None) or {}
+            measured_flows: List[TL.Flow] = []
+            last_done: Dict[Any, float] = {}
+            for i, rec, out, post, t_launch, t_done in tasks:
+                resources = self._record_resources(rec)
+                t_ready = max([t_launch]
+                              + [last_done.get(r, 0.0) for r in resources])
+                wall = max(t_done - t_ready, 1e-9)
+                for r in resources:
+                    last_done[r] = max(last_done.get(r, 0.0), t_done)
+                if rec.stages:
+                    meas = self._apportion(rec, wall, sel_times, plan.step)
+                    measured_flows.append(self._measured_flow(rec, i, meas))
+                post(out, parts)
+            outputs = {rid: merge_tree(ps) for rid, ps in parts.items()}
+            analytic = self._analytic_timeline(plan)
+            report = self._report(plan, analytic, measured_flows, t_wall0,
+                                  "fused")
         return StepExecution(timeline=analytic, outputs=outputs,
                              backend=self.name, measured=report)
 

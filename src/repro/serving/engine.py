@@ -48,6 +48,7 @@ from repro.core import cost_model as cm
 from repro.core import predicate as P
 from repro.core.chunk_store import ChunkStore
 from repro.core.constants import Fabric
+from repro.obs import phases
 from repro.serving import timeline as TL
 from repro.serving.backends.base import (ExecutionBackend, StepExecution,
                                          StepTicket, await_step, submit_step)
@@ -1489,7 +1490,9 @@ class ServingEngine:
                 # work was still un-awaited: it is overlap if that step's
                 # await turns out to actually block
                 self._inflight[0].overlap_candidate_s += plan_wall
-        ticket = submit_step(self.backend, self, plan)
+        phases.open_step(plan.step)
+        with phases.span("submit", plan.step):
+            ticket = submit_step(self.backend, self, plan)
         t_submit1 = time.perf_counter()
         self._inflight.append(_InFlight(
             plan=plan, ticket=ticket, t_plan0=t_plan0, t_plan1=t_plan1,
@@ -1561,7 +1564,8 @@ class ServingEngine:
         order, which the backends require)."""
         entry = self._inflight.pop(0)
         t_await0 = time.perf_counter()
-        execution = await_step(self.backend, self, entry.ticket)
+        with phases.span("await", entry.plan.step):
+            execution = await_step(self.backend, self, entry.ticket)
         t_await1 = time.perf_counter()
         await_wall = t_await1 - t_await0
         # the await blocked => the device was busy from submit straight
@@ -1634,6 +1638,7 @@ class ServingEngine:
                 prim_counts[r.primitive] += 1
         timeline = execution.timeline
         self.timelines.append(timeline)
+        phase_s, phase_n = phases.close_step(plan.step)
         self.stats.append(StepStats(
             step=plan.step, n_requests=len(plan.requests),
             n_pairs=plan.n_pairs, n_priced=plan.n_priced,
@@ -1651,7 +1656,8 @@ class ServingEngine:
             stage_totals=timeline.stage_totals(),
             n_selected=sum(len(rq.chunk_ids) for rq in plan.requests
                            if rq.req_id in plan.selections),
-            selection_fallbacks=plan.selection_fallbacks))
+            selection_fallbacks=plan.selection_fallbacks,
+            phase_s=phase_s, phase_n=phase_n))
 
     # -- multi-step driver -----------------------------------------------------
 

@@ -152,15 +152,20 @@ class StepStats:
     # recorded here, so the divergence is always visible in telemetry)
     n_selected: int = 0
     selection_fallbacks: int = 0
+    # host clock, like sched_wall_s: the execution backend's self seconds
+    # and count of each phase of this step (repro.obs.phases)
+    phase_s: Dict[str, float] = dataclasses.field(default_factory=dict)
+    phase_n: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     def comparable(self) -> Dict[str, object]:
         """Everything deterministic about the step: the full dataclass
-        minus sched_wall_s (host wall clock — the one field that may
-        legitimately differ between two runs of the same plan). A/B
-        identity checks (pipelined vs lockstep, obs on vs off) compare
-        this dict."""
+        minus the host-clock fields (sched_wall_s, phase_s, phase_n —
+        they may legitimately differ between two runs or two backends of
+        the same plan). A/B identity checks (pipelined vs lockstep, obs
+        on vs off) compare this dict."""
         d = dataclasses.asdict(self)
-        d.pop("sched_wall_s")
+        for k in ("sched_wall_s", "phase_s", "phase_n"):
+            d.pop(k)
         return d
 
     @property

@@ -6,7 +6,7 @@ of the object path, not an approximation. Three layers of lockdown:
     on randomized flow sets, zero-duration stages included; negative
     durations delegate to the object oracle by contract;
   * planner A/B — EngineConfig.vectorized_plan False vs True produces
-    bitwise-identical DispatchRecords and StepStats (sched_wall_s aside)
+    bitwise-identical DispatchRecords and StepStats (host clocks aside)
     over every golden scenario, the selection trace, and a multi-step
     randomized workload with evictions and replica spawns;
   * round trip — StepPlanArrays.to_records()/from_records() loses
@@ -42,11 +42,7 @@ def _drive(eng, steps):
     for reqs in steps:
         eng.schedule_step(reqs)
     recs = [dataclasses.astuple(r) for r in eng.log]
-    stats = []
-    for s in eng.stats:
-        d = dataclasses.asdict(s)
-        d.pop("sched_wall_s")           # the only non-simulated field
-        stats.append(d)
+    stats = [s.comparable() for s in eng.stats]   # host clocks dropped
     residency = sorted(
         (cid, c.holder, tuple(sorted(c.replicas)), c.last_access)
         for cid, c in eng.store._chunks.items())
