@@ -177,6 +177,9 @@ class Obs:
         for phase, secs in stats.phase_s.items():
             g = m.gauge("exec.phase_wall_s", phase=phase)
             g.set(g.value + secs)
+        programs = getattr(engine.backend, "partial_programs", None)
+        if programs is not None:
+            m.gauge("exec.partial_programs").set(programs)
         if report is not None:
             if report.stage_fills:
                 # satellite (ISSUE 9): stage-measurement gaps per-run, not

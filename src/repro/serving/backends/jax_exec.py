@@ -35,6 +35,14 @@ output per request equals single-instance attention over the concatenated
 chunks to float round-off REGARDLESS of which primitive the predicate
 picked (§3.3, now end-to-end through the scheduler).
 
+Every holder partial runs as one compiled program (`HolderPrograms`):
+`holder_partial` for a resident, local or fetched access and for a
+one-member route, keyed by the query's m_q, the chunk length and whether
+a mask is present; a route of several members first writes each query
+into a zeroed buffer of `route_bucket` rows (`stack_query`), attends it
+with one `holder_partial`, and takes each member's rows back
+(`take_rows`), so no key grows with the group's composition.
+
 The analytic stage costs ride along unchanged: the returned timeline is
 the same schedule the AnalyticBackend produces, so planner parity and
 StepStats parity hold by construction.
@@ -44,11 +52,13 @@ from __future__ import annotations
 
 import zlib
 from collections import defaultdict
-from typing import Dict, List, Optional, Tuple, TYPE_CHECKING
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple, \
+    TYPE_CHECKING
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core.chunk_store import ChunkStore
 from repro.core.merge import Partial, merge_tree
@@ -190,6 +200,56 @@ def max_oracle_err(engine: "ServingEngine", reqs: List[Request],
     return worst
 
 
+# the fewest rows a multi-member route's stacked query buffer has: one
+# bf16 sublane tile
+MIN_ROUTE_ROWS = 16
+
+
+def route_bucket(rows: int) -> int:
+    """Rows of the buffer a route group of `rows` stacked query rows runs
+    in: the next power of two, at least MIN_ROUTE_ROWS. Padded rows are
+    zero queries whose results are dropped."""
+    return max(MIN_ROUTE_ROWS, 1 << (rows - 1).bit_length())
+
+
+class HolderPrograms(NamedTuple):
+    """The compiled programs of the holder partials, named so that a
+    profile shows holder compute as `jit_holder_partial`.
+
+    partial(q, ckv, mask=None, *, routed=False) -> Partial: one holder's
+      absorbed partial; `routed` traces it as core.routing.route_batched
+      of one group on one holder (the same math), as a ROUTE dispatch is.
+    stack(buf, q, row) -> buf with q written at `row` (a traced offset).
+    take(part, row, *, rows) -> the `rows` rows of `part` from `row`."""
+    partial: Callable
+    stack: Callable
+    take: Callable
+
+
+def holder_programs(cfg: MLAConfig) -> HolderPrograms:
+    """Build the holder-partial programs for `cfg`. Each backend builds
+    its own: a program is traced when the backend first calls it with a
+    new shape, from the functions this module names at that time."""
+
+    def holder_partial(q, ckv, mask=None, *, routed=False) -> Partial:
+        if routed:
+            masks = None if mask is None else [[mask]]
+            return route_batched(cfg, [q], [[ckv]], masks)[0]
+        return absorbed_partial(cfg, q, ckv, mask)
+
+    def stack_query(buf, q, row):
+        return lax.dynamic_update_slice_in_dim(buf, q, row, axis=0)
+
+    def take_rows(part, row, *, rows) -> Partial:
+        return Partial(*(lax.dynamic_slice_in_dim(x, row, rows, axis=0)
+                         for x in part))
+
+    return HolderPrograms(
+        partial=jax.jit(holder_partial, static_argnames="routed"),
+        stack=jax.jit(stack_query),
+        take=jax.jit(take_rows, static_argnames="rows"))
+
+
 class JaxExecBackend:
     """Execute StepPlans on real arrays. cfg sets the EXECUTION geometry
     (array shapes); it is independent of the planner's cost payload."""
@@ -206,6 +266,26 @@ class JaxExecBackend:
         # previous step are pruned when a new step arrives.
         self._qmemo: Dict[Tuple[int, int, int], jax.Array] = {}
         self._qmemo_step = -1
+        # the holder-partial programs, built per backend, and the distinct
+        # (program, shapes) keys they have been called with
+        self._programs = holder_programs(cfg)
+        self._program_keys: set = set()
+        self._zero_rows: Dict[int, jax.Array] = {}
+
+    @property
+    def partial_programs(self) -> int:
+        """Distinct holder-partial programs (stack and take helpers
+        included) this backend has built: one per key called."""
+        return len(self._program_keys)
+
+    def holder_partial(self, q: jax.Array, ckv: jax.Array,
+                       mask: Optional[jax.Array] = None, *,
+                       routed: bool = False) -> Partial:
+        """One holder's partial as one compiled program, keyed by the
+        query's rows, the chunk's length and whether a mask is present."""
+        self._program_keys.add(("holder_partial", routed, q.shape,
+                                ckv.shape, mask is not None))
+        return self._programs.partial(q, ckv, mask, routed=routed)
 
     def query_of(self, rq: Request, step: int) -> jax.Array:
         """Memoized query_for: the request's decode queries this step. A
@@ -277,8 +357,8 @@ class JaxExecBackend:
                               holder=rp.instance):
                 arr = self._array_on(store, rp.chunk_id, rp.instance)
                 parts[rp.req_id].append(
-                    absorbed_partial(self.cfg, q_of(rp.req_id), arr,
-                                     mask_of(rp.req_id, rp.chunk_id)))
+                    self.holder_partial(q_of(rp.req_id), arr,
+                                        mask_of(rp.req_id, rp.chunk_id)))
 
         for rec in plan.records:
             if rec.backup or not rec.req_ids:
@@ -298,8 +378,8 @@ class JaxExecBackend:
                     arr = self.ensure_chunk_data(store, rec.chunk_id)
                     for rid in rec.req_ids:
                         parts[rid].append(
-                            absorbed_partial(self.cfg, q_of(rid), arr,
-                                             mask_of(rid, rec.chunk_id)))
+                            self.holder_partial(q_of(rid), arr,
+                                                mask_of(rid, rec.chunk_id)))
 
         outputs = {}
         for rid, ps in parts.items():
@@ -321,27 +401,39 @@ class JaxExecBackend:
 
     def _exec_route(self, store: ChunkStore, rec, q_of, parts,
                     mask_of) -> None:
-        """One batched dispatch: stack the group's queries, one holder-side
-        partial over the holder's resident copy, slice back per request.
-        A selection-regime dispatch (single-request by construction)
-        routes as a MASKED partial — the holder attends selected &
-        resident in place (§5.4), the block-sparse shape
+        """One batched dispatch: one holder-side partial over the holder's
+        resident copy for the whole group. A one-member group attends its
+        own query; a larger group stacks its queries into a zeroed buffer
+        of route_bucket(rows) rows, attends the buffer, and takes each
+        member's rows back. A selection-regime dispatch (single-request by
+        construction) routes as a MASKED partial — the holder attends
+        selected & resident in place (§5.4), the block-sparse shape
         kernels/sparse_select computes."""
         holder_arr = self._array_on(store, rec.chunk_id, rec.holder)
+        if len(rec.req_ids) == 1:
+            rid = rec.req_ids[0]
+            parts[rid].append(self.holder_partial(
+                q_of(rid), holder_arr, mask_of(rid, rec.chunk_id),
+                routed=True))
+            return
         qs = [q_of(rid) for rid in rec.req_ids]
-        mask = mask_of(rec.req_ids[0], rec.chunk_id)
-        if mask is not None:
-            merged = route_batched(self.cfg, [qs[0]], [[holder_arr]],
-                                   masks=[[mask]])[0]
-        else:
-            stacked = jnp.concatenate(qs, axis=0) if len(qs) > 1 else qs[0]
-            merged = route_batched(self.cfg, [stacked], [[holder_arr]])[0]
+        rows = [q.shape[0] for q in qs]
+        bucket = route_bucket(sum(rows))
+        progs, keys = self._programs, self._program_keys
+        buf = self._zero_rows.get(bucket)
+        if buf is None:           # made once per bucket; programs copy it
+            buf = self._zero_rows[bucket] = jnp.zeros(
+                (bucket,) + qs[0].shape[1:], qs[0].dtype)
         off = 0
-        for rid, q in zip(rec.req_ids, qs):
-            n = q.shape[0]
-            parts[rid].append(Partial(o=merged.o[off:off + n],
-                                      m=merged.m[off:off + n],
-                                      l=merged.l[off:off + n]))
+        for q, n in zip(qs, rows):
+            keys.add(("stack_query", bucket, n))
+            buf = progs.stack(buf, q, off)
+            off += n
+        merged = self.holder_partial(buf, holder_arr, routed=True)
+        off = 0
+        for rid, n in zip(rec.req_ids, rows):
+            keys.add(("take_rows", bucket, n))
+            parts[rid].append(progs.take(merged, off, rows=n))
             off += n
 
     def _exec_fetch(self, store: ChunkStore, rec, q_of, parts) -> None:
@@ -362,7 +454,7 @@ class JaxExecBackend:
             if keys is not None:
                 store.set_replica_index_keys(rec.chunk_id, dest, keys)
         for rid in rec.req_ids:
-            parts[rid].append(absorbed_partial(self.cfg, q_of(rid), moved))
+            parts[rid].append(self.holder_partial(q_of(rid), moved))
 
     def _exec_fetch_selected(self, store: ChunkStore, rec, q_of, parts,
                              sel) -> None:
@@ -392,5 +484,4 @@ class JaxExecBackend:
             return
         src_arr = self._array_on(store, rec.chunk_id, fetch_source(rec))
         gathered = jnp.take(src_arr, jnp.asarray(idx), axis=0)
-        parts[rid].append(
-            absorbed_partial(self.cfg, q_of(rid), gathered))
+        parts[rid].append(self.holder_partial(q_of(rid), gathered))

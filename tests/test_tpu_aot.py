@@ -18,8 +18,10 @@ from repro.kernels.flash_prefill import flash_prefill
 from repro.kernels.mla_decode import mla_decode
 from repro.kernels.softmax_merge import softmax_merge
 from repro.kernels.sparse_select import sparse_select_decode
+from repro.core.merge import Partial
 from repro.models.mla import absorbed_partial
 from repro.serving.backends.jax_exec import DEEPSEEK_V2_MLA as CFG
+from repro.serving.backends.jax_exec import holder_programs, route_bucket
 
 H, D_QK, D_V = CFG.n_heads, CFG.d_qk, CFG.kv_lora_rank
 S, B = 2048, 8
@@ -97,6 +99,30 @@ def test_absorbed_partial_compiles_for_v5e(one_chip):
     o, m, l = compiled.out_info
     assert (o.shape, m.shape, l.shape) == ((16, H, D_V), (16, H), (16, H))
     assert o.dtype == m.dtype == l.dtype == jnp.float32
+
+
+def test_bucketed_route_programs_compile_for_v5e(one_chip):
+    """The exec backend's multi-member ROUTE at its largest bucket: six
+    16-row members stacked into 128 rows x 128 heads x 576, one holder
+    partial against a 2048-token chunk, and a member's rows taken back."""
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    progs, rows, m_q = holder_programs(CFG), route_bucket(6 * 16), 16
+    assert rows == 128
+    row = spec((), jnp.int32)
+    stacked = progs.stack.lower(spec((rows, H, D_QK)), spec((m_q, H, D_QK)),
+                                row).compile()
+    assert stacked.out_info.shape == (rows, H, D_QK)
+    o, m, l = progs.partial.lower(spec((rows, H, D_QK)), spec((S, D_QK)),
+                                  routed=True).compile().out_info
+    assert (o.shape, m.shape, l.shape) == ((rows, H, D_V), (rows, H),
+                                           (rows, H))
+    assert o.dtype == m.dtype == l.dtype == jnp.float32
+    f32 = jnp.float32
+    part = Partial(spec((rows, H, D_V), f32), spec((rows, H), f32),
+                   spec((rows, H), f32))
+    taken = progs.take.lower(part, row, rows=m_q).compile().out_info
+    assert [x.shape for x in taken] == [(m_q, H, D_V), (m_q, H), (m_q, H)]
 
 
 def test_fused_route_pair_program_compiles_on_2x2(topo):
