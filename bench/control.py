@@ -6,9 +6,10 @@ gap and the fp8 control's, seed by seed, in one process.
 
 Each seed is a full run of the cell (set-up, warm-up, a short window at
 the cell's own load, the sample drawn from the seed) whose check also
-puts the control in the program's place: the reference with the queries
-and the latent cache in float8_e4m3fn (reference.attend_fp8), held to
-the configuration's limit by the same predicate. The limit lies between
+puts the control in the program's place: the configuration's `control`
+hook, by default the reference with the queries and the latent cache in
+float8_e4m3fn (reference.attend_fp8), held to the configuration's limit
+by the same predicate. The limit lies between
 the largest program gap and the smallest control gap (PERF.md gives the
 readings). Exits 1 where the program reads not correct or the control
 reads correct on any seed. The benchmark's own runs never compute the

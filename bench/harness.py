@@ -5,6 +5,37 @@ plain reference.
 Everything specific to a cell is data found by name from BENCHMARK.json:
 the configuration file it names, `<paths[0]>/traffic/<traffic>.json`,
 and one reader per per-layer metric in `<paths[0]>/metrics/<name>.py`.
+
+A configuration may also name a module of model hooks, `"model":
+"<path under paths[0]>"` (for example `"models/dsv32-dsa.py"`), which
+the harness loads by path as it loads the readers. The module may define
+any of the hooks below; each one it leaves out is the harness's own.
+
+* `make_backend(config)`: the execution backend (default `make_backend`).
+* `build_engine(config, chunks, backend, seed)`: the engine over the
+  corpus, `chunks` being the chunk arrays drawn from `seed` in corpus
+  order, with whatever seeded state and selector the model needs
+  (default `build_engine`). The rehearsal's throwaway engine and the
+  served engine are both built by it.
+* `program_keys(plan, placed)`: what a step's programs are keyed by,
+  for the rehearsal (default `program_keys`).
+* `step_flops(requests, config)`: the FLOPs the step required, counted
+  from shapes; `requests` are `workload.Req` (default `step_flops`).
+* `expected(config, seed, request, step, chunk_ids, chunk_arrays,
+  dtype)`: the float32 reference output of one sampled request at the
+  step that served it, `chunk_arrays` being its chunks' arrays in
+  `chunk_ids` order, on one device (default `reference.expected`).
+* `control(...)`, with `expected`'s arguments: the same output in the
+  precision below the configuration's, which the check must refuse
+  (default `reference.control`).
+
+`expected` and `control` are the reference: they use nothing of the
+program and nothing it made, only the configuration, the seed and the
+chunk arrays the benchmark drew. A public name of the module outside
+this list is an error at set-up, so a misspelt hook cannot fall back in
+silence; helpers and constants are private (`_name`). Whatever the hooks
+do, every sampled output is held to the configuration's
+`check.max_abs_err` by one `Reading`, its shape against the reference's.
 """
 
 from __future__ import annotations
@@ -21,8 +52,9 @@ import shutil
 import sys
 import tempfile
 import time
+import types
 import zlib
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
@@ -86,14 +118,56 @@ def load_cell(root: pathlib.Path, name: str) -> Cell:
                 bench_dir=bench_dir)
 
 
-def metric_reader(bench_dir: pathlib.Path, name: str):
-    """The `read(window)` function of bench_dir/metrics/<name>.py."""
-    path = bench_dir / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
+def load_module(path: pathlib.Path, name: str) -> types.ModuleType:
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(bench_dir: pathlib.Path, name: str):
+    """The `read(window)` function of bench_dir/metrics/<name>.py."""
+    return load_module(bench_dir / "metrics" / f"{name}.py",
+                       f"bench_metric_{name}").read
+
+
+@dataclasses.dataclass(frozen=True)
+class Model:
+    """The hooks a configuration runs with (module docstring)."""
+    make_backend: Callable
+    build_engine: Callable
+    program_keys: Callable
+    step_flops: Callable
+    expected: Callable
+    control: Callable
+
+
+HOOKS = tuple(f.name for f in dataclasses.fields(Model))
+
+
+def load_model(bench_dir: pathlib.Path, config: dict) -> Model:
+    """The configuration's hooks: those of the module its `"model"` key
+    names, the harness's own for the rest."""
+    hooks = {"make_backend": make_backend, "build_engine": build_engine,
+             "program_keys": program_keys, "step_flops": step_flops,
+             "expected": R.expected, "control": R.control}
+    rel = config.get("model")
+    if rel is None:
+        return Model(**hooks)
+    path = (bench_dir / rel).resolve()
+    if not path.is_file() or bench_dir.resolve() not in path.parents:
+        raise SetupError(f"model {rel!r} is no file under {bench_dir}")
+    mod = load_module(path, f"bench_model_{path.stem.replace('-', '_')}")
+    public = {n for n, v in vars(mod).items()
+              if not n.startswith("_")
+              and not isinstance(v, types.ModuleType)
+              and getattr(v, "__module__", mod.__name__) == mod.__name__}
+    unknown = sorted(public - set(HOOKS))
+    if unknown:
+        raise SetupError(f"model {rel!r} defines {unknown}, which are no "
+                         f"hooks; hooks: {list(HOOKS)}")
+    hooks.update({name: getattr(mod, name) for name in public})
+    return Model(**hooks)
 
 
 # -- what one window recorded --------------------------------------------------
@@ -247,27 +321,40 @@ def peak_of(kind: str, table: Optional[dict] = None) -> dict:
 CHUNK_GROUP = 64
 
 
-def make_chunks(jax, seed: int, n: int, tokens: int, width: int, dtype):
+def make_chunks(jax, seed: int, n: int, tokens: int, width: int, dtype,
+                devices=(None,)):
     """Every chunk's latent array, made on the device from the seed by one
     jitted program: n arrays of (tokens, width) standard normals in the
-    served dtype. The program draws CHUNK_GROUP chunks at a time, so the
-    draw's transient stays a group's size and not the corpus's."""
+    served dtype, chunk i from the seed's key folded with i. Chunk i is
+    drawn on devices[i % len(devices)]: with one instance per chip, the
+    chip of the instance that holds it (workload.holder_of); None is
+    JAX's default device, uncommitted. The program draws CHUNK_GROUP
+    chunks at a time, so the draw's transient stays a group's size and
+    not the corpus's."""
     import jax.numpy as jnp
-    group = min(n, CHUNK_GROUP)
+    k = len(devices)
+    per = -(-n // k)
+    group = min(per, CHUNK_GROUP)
 
     @jax.jit
-    def gen(key, first):
-        keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(
-            first + jnp.arange(group))
+    def gen(key, idx):
+        keys = jax.vmap(lambda i: jax.random.fold_in(key, i))(idx)
         x = jax.vmap(lambda k: jax.random.normal(k, (tokens, width),
                                                  dtype))(keys)
         return tuple(x[i] for i in range(group))
 
     key = jax.random.fold_in(jax.random.PRNGKey(seed % 2 ** 32), seed >> 32)
-    out = []
-    for first in range(0, n, group):
-        out.extend(gen(key, first))
-    return out[:n]
+    out = [None] * n
+    for h, dev in enumerate(devices):
+        for first in range(0, per, group):
+            idx = (h + k * (first + np.arange(group))).astype(np.int32)
+            args = (key, jnp.asarray(idx))
+            if dev is not None:
+                args = jax.device_put(args, dev)
+            for i, x in zip(idx.tolist(), gen(*args)):
+                if i < n:
+                    out[i] = x
+    return out
 
 
 def make_backend(config: dict):
@@ -292,9 +379,10 @@ def make_backend(config: dict):
     return B(cfg=mla, dtype=jnp.dtype(srv["dtype"]))
 
 
-def build_engine(config: dict, chunks: list, backend):
+def build_engine(config: dict, chunks: list, backend, seed: int):
     """The engine, straight from the configuration file, with the corpus
-    registered and every chunk's array attached."""
+    registered and every chunk's array attached. `seed` is not needed
+    here: the chunks already carry its data."""
     from repro.serving.engine import EngineConfig, ServingEngine
     srv, corpus = config["serving"], config["corpus"]
     n_inst = srv["instances"]
@@ -330,6 +418,14 @@ def program_keys(plan, placed: bool) -> set:
     return keys
 
 
+def step_flops(requests, config: dict) -> int:
+    """The dense attention FLOPs of a step (bench/flops.py) at the
+    configuration's geometry."""
+    geom = R.Geometry.of(config)
+    return F.step_flops(requests, config["corpus"]["chunk_tokens"],
+                        geom.n_heads, geom.d_qk, geom.d_v)
+
+
 # steps a second the rehearsal provides for: about 1.5 times the rate at
 # which the served step runs on a v5e (PERF.md, section 6)
 REHEARSE_STEPS_PER_S = 5.0
@@ -341,14 +437,14 @@ def rehearse_horizon(traffic: W.Traffic, seconds: float) -> int:
     return traffic.warmup_steps + math.ceil(seconds * REHEARSE_STEPS_PER_S)
 
 
-def rehearse(jax, config: dict, chunks: list, backend, traffic: W.Traffic,
-             seed: int, steps: int) -> int:
+def rehearse(jax, model: Model, config: dict, chunks: list, backend,
+             traffic: W.Traffic, seed: int, steps: int) -> int:
     """Compile, in set-up, the programs the run's first `steps` steps will
     need. A throwaway engine plans each of those steps (plans are
     deterministic, so they equal the served engine's) and executes, on the
     backend the served engine will use, every step whose plan shows a
     program key not seen before. Returns the steps executed."""
-    eng = build_engine(config, chunks, backend)
+    eng = model.build_engine(config, chunks, backend, seed)
     gen = W.sessions(traffic, config["corpus"]["chunks"],
                      config["serving"]["instances"], seed)
     placed = config["serving"]["chips"] > 1
@@ -356,7 +452,7 @@ def rehearse(jax, config: dict, chunks: list, backend, traffic: W.Traffic,
     executed = 0
     for _ in range(steps):
         plan = eng.plan_step([to_request(r) for r in next(gen)])
-        keys = program_keys(plan, placed)
+        keys = model.program_keys(plan, placed)
         if keys <= seen:
             continue
         seen |= keys
@@ -396,13 +492,12 @@ class ServeLoop:
     accounted step's outputs. A request is due when its step is handed to
     schedule_step and done when its output is ready."""
 
-    def __init__(self, jax, eng, gen, geom: R.Geometry, chunk_tokens: int,
+    def __init__(self, jax, eng, gen, flops: Callable[[list], int],
                  timer: Optional[HostTimer]):
         self.jax = jax
         self.eng = eng
         self.gen = gen
-        self.geom = geom
-        self.chunk_tokens = chunk_tokens
+        self.flops = flops
         self.timer = timer
         self.depth = max(1, eng.cfg.pipeline_depth)
         self.ahead = next(gen)
@@ -467,10 +562,8 @@ class ServeLoop:
                 self._exec_mark = self.timer.exec_s
             self.records.append(StepRecord(
                 stats=stats, plan_s=eng.plan_walls[i], exec_s=exec_s,
-                flops=F.step_flops(reqs, self.chunk_tokens,
-                                   self.geom.n_heads, self.geom.d_qk,
-                                   self.geom.d_v),
-                rows=sum(r.m_q for r in reqs), n_requests=len(reqs)))
+                flops=self.flops(reqs), rows=sum(r.m_q for r in reqs),
+                n_requests=len(reqs)))
             if self.sampler is not None:
                 kinds = kinds_of(plan)
                 top = max(r.m_q for r in reqs)
@@ -519,29 +612,30 @@ class Reading:
         return self.over == 0 and self.n > 0
 
 
-def check(jax, entries: List[dict], chunks: list, geom: R.Geometry,
-          dtype, limit: float, control: bool = False):
-    """Each sampled request's served output against the reference over its
-    concatenated chunks. With `control`, the fp8 control is put in the
+def check(entries: List[dict], chunks: list, model: Model, config: dict,
+          seed: int, dtype, limit: float, device, control: bool = False):
+    """Each sampled request's served output against the model's reference
+    (`expected`). With `control`, the model's control is put in the
     program's place on the same requests and read by the same predicate.
-    Returns the program's Reading and the control's (or None)."""
-    import jax.numpy as jnp
+    An output of another shape than the reference's reads an infinite
+    gap. The reference gets a request's chunk arrays on `device`, one
+    device, wherever the program holds them. Returns the program's
+    Reading and the control's (or None)."""
+    import jax
     index = {cid: i for i, cid in enumerate(W.chunk_ids(len(chunks)))}
     served = Reading(limit)
     ctrl = Reading(limit) if control else None
     for e in entries:
         r = e["req"]
-        q = R.query(geom, r.query_seed, e["step"], r.m_q, dtype)
-        cat = jnp.concatenate([chunks[index[c]] for c in r.chunk_ids])
-        want = R.attend(q, cat, d_v=geom.d_v, scale=geom.scale)
+        arrays = [jax.device_put(chunks[index[c]], device)
+                  for c in r.chunk_ids]
+        args = (config, seed, r, e["step"], r.chunk_ids, arrays, dtype)
+        want = model.expected(*args)
         o = e["o"]
-        if tuple(o.shape) != (r.m_q, geom.n_heads, geom.d_v):
-            served.add(float("inf"))
-        else:
-            served.add(R.gap(o, want))
+        served.add(R.gap(o, want) if tuple(o.shape) == tuple(want.shape)
+                   else float("inf"))
         if ctrl is not None:
-            ctrl.add(R.gap(R.attend_fp8(q, cat, d_v=geom.d_v,
-                                        scale=geom.scale), want))
+            ctrl.add(R.gap(model.control(*args), want))
     return served, ctrl
 
 
@@ -554,6 +648,8 @@ class Run:
     check_lines: List[str]
     # the control's verdict by the program's predicate, where it was run
     control_correct: Optional[bool] = None
+    # what the per-layer readers read
+    window: Optional[Window] = None
 
 
 def run(root, workload: str, seed: int, seconds: float, trace: bool,
@@ -564,6 +660,7 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool,
     log = log or (lambda msg: print(f"[bench] {msg}", file=sys.stderr,
                                     flush=True))
     cell = load_cell(root, workload)
+    model = load_model(cell.bench_dir, cell.config)
     import jax
     import jax.numpy as jnp
     devices = devices_for(jax, cell.chips, require_tpu)
@@ -579,13 +676,16 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool,
     log(f"{workload}: {kind} x {len(devices)}, seed {seed}, compile cache "
         f"{cache}")
 
+    # on several chips each chunk is drawn on its holder's chip, where the
+    # shard_map backend serves it from
+    on = devices[:srv["instances"]] if cell.chips > 1 else (None,)
     chunks = make_chunks(jax, seed, corpus["chunks"], corpus["chunk_tokens"],
-                         geom.d_qk, dtype)
-    backend = make_backend(cfg)
+                         geom.d_qk, dtype, on)
+    backend = model.make_backend(cfg)
     horizon = rehearse_horizon(cell.traffic, seconds)
-    rehearsed = rehearse(jax, cfg, chunks, backend, cell.traffic, seed,
-                         horizon)
-    eng = build_engine(cfg, chunks, backend)
+    rehearsed = rehearse(jax, model, cfg, chunks, backend, cell.traffic,
+                         seed, horizon)
+    eng = model.build_engine(cfg, chunks, backend, seed)
     timer = None
     if trace:
         timer = HostTimer(jax, annotate=True)
@@ -594,7 +694,8 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool,
         timer.wrap(eng.backend, "submit", timed=True)
         timer.wrap(eng.backend, "await_result", timed=True)
     gen = W.sessions(cell.traffic, corpus["chunks"], srv["instances"], seed)
-    drv = ServeLoop(jax, eng, gen, geom, corpus["chunk_tokens"], timer)
+    drv = ServeLoop(jax, eng, gen, lambda reqs: model.step_flops(reqs, cfg),
+                    timer)
     for _ in range(cell.traffic.warmup_steps):
         drv.step()
     drv.finish()
@@ -636,9 +737,9 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool,
             summary = TR.reduce(TR.extract(found[0]))
         shutil.rmtree(tmp, ignore_errors=True)
 
-    used = devices[:cell.chips]
-    memory_peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-                      for d in used)
+    peaks_used = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+                  for d in devices[:cell.chips]]
+    memory_peak = max(peaks_used)
     attempted = sum(r.n_requests for r in drv.records)
     unfinished = attempted - drv.done
     entries = drv.sampler.entries()
@@ -646,8 +747,8 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool,
     del eng, drv.eng, timer, backend
     gc.collect()
     limit = cfg["check"]["max_abs_err"]
-    served, ctrl = check(jax, entries, chunks, geom, dtype, limit,
-                         control=control)
+    served, ctrl = check(entries, chunks, model, cfg, seed, dtype, limit,
+                         devices[0], control=control)
 
     window = Window(steps=drv.records, seconds=window_s, compiles=compiles,
                     chips=cell.chips, peak=peak, trace=summary)
@@ -676,7 +777,8 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool,
                                          "limit": limit}
         checks["control_over_limit"] = {"value": ctrl.over, "limit": 0}
     device = {"platform": devices[0].platform, "kind": kind,
-              "count": len(devices), "memory_peak_bytes": int(memory_peak)}
+              "count": len(devices), "memory_peak_bytes": int(memory_peak),
+              "memory_peak_bytes_by_chip": [int(b) for b in peaks_used]}
     result = {"correct": correct, "attempted": attempted,
               "failed": unfinished + served.over,
               "metrics": metrics, "device": device}
@@ -695,11 +797,13 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool,
         f"latency ms p50 {percentile(lat, 50) * 1e3:.1f} "
         f"p95 {percentile(lat, 95) * 1e3:.1f} max {lat[-1] * 1e3:.1f}; "
         f"slowest steps (index, ms) {drv.slowest(5)}; "
-        f"memory peak {memory_peak / 1e9:.3f} GB; sampled {served.n} "
-        f"requests")
+        f"memory peak by chip "
+        f"{' '.join(f'{b / 1e9:.3f}' for b in peaks_used)} GB; sampled "
+        f"{served.n} requests")
     result["checks"] = checks
     lines = [f"check {k}: {v['value']} (limit {v['limit']})"
              for k, v in checks.items()]
     return Run(result=result, check_lines=lines,
                control_correct=(None if ctrl is None
-                                else ctrl.passes and finished))
+                                else ctrl.passes and finished),
+               window=window)

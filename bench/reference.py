@@ -73,6 +73,30 @@ def attend_fp8(q, ckv, *, d_v: int, scale: float):
                   ckv.astype(f8).astype(jnp.float32), d_v=d_v, scale=scale)
 
 
+def _dense(config: dict, request, step: int, chunk_arrays, dtype):
+    geom = Geometry.of(config)
+    q = query(geom, request.query_seed, step, request.m_q, dtype)
+    return geom, q, jnp.concatenate(chunk_arrays)
+
+
+def expected(config: dict, seed: int, request, step: int, chunk_ids,
+             chunk_arrays, dtype):
+    """The harness's default `expected` hook: `attend` over the request's
+    concatenated chunks, with its queries at `step`. `seed` and
+    `chunk_ids` are not needed: the arrays carry the seed's data in the
+    request's chunk order."""
+    geom, q, cat = _dense(config, request, step, chunk_arrays, dtype)
+    return attend(q, cat, d_v=geom.d_v, scale=geom.scale)
+
+
+def control(config: dict, seed: int, request, step: int, chunk_ids,
+            chunk_arrays, dtype):
+    """The harness's default `control` hook: `expected` in fp8
+    (`attend_fp8`)."""
+    geom, q, cat = _dense(config, request, step, chunk_arrays, dtype)
+    return attend_fp8(q, cat, d_v=geom.d_v, scale=geom.scale)
+
+
 def gap(got, want) -> float:
     """Largest absolute difference of one output against its reference,
     on the host, wherever the two arrays live."""

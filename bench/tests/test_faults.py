@@ -5,17 +5,13 @@ of the program's functions broken, and `correct` has to come out false.
 One chip: a step that hands back the previous step's state, half of each
 chunk left out of the softmax, the query exchange left out, an answer
 altered where it is produced. Four chips (a subprocess with four host
-devices): the chips' exchange left out."""
-
-import json
-import os
-import subprocess
-import sys
-import textwrap
+devices, the shard_map backend): the same faults planted there, the
+chips' exchange left out among them."""
 
 import pytest
 
-from conftest import BENCH, CPU_PEAKS, ROOT, TINY_CELL, make_root
+from conftest import (CPU_PEAKS, FOUR_CHIP_FAULTS, TINY_CELL, make_root,
+                      run_four_chips)
 
 import harness
 
@@ -96,37 +92,12 @@ def test_planted_fault_is_not_correct(fault, tmp_path, compile_cache,
     assert chk["value"] > chk["limit"]
 
 
-FOUR_CHIPS = textwrap.dedent("""
-    import json, sys
-    sys.path[:0] = [{src!r}, {bench!r}, {tests!r}]
-    import jax.numpy as jnp
-    import harness
-    from conftest import CPU_PEAKS, TINY_CELL
-    if {fault!r}:
-        from repro.serving.backends import shard_map as S
-        for name in ("pairwise_ship", "fanout_gather"):
-            orig = getattr(S, name)
-            setattr(S, name, lambda *a, _o=orig, **k: _o(*a, **k) * 0)
-    res = harness.run({root!r}, TINY_CELL, 2_200_000_654, 0.5, False,
-                      require_tpu=False, peaks=CPU_PEAKS,
-                      log=lambda msg: None).result
-    print(json.dumps(res))
-""")
-
-
-@pytest.mark.parametrize("fault", [False, True],
-                         ids=["sound", "exchange_left_out"])
+@pytest.mark.parametrize("fault", ["", *FOUR_CHIP_FAULTS],
+                         ids=lambda f: f or "sound")
 def test_four_chips_exchange_left_out_is_not_correct(fault, tmp_path):
-    root = make_root(tmp_path / "root", chips=4, backend="shard_map")
-    code = FOUR_CHIPS.format(src=str(ROOT / "src"), bench=str(BENCH),
-                             tests=str(BENCH / "tests"), fault=fault,
-                             root=str(root))
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jc"),
-               XLA_FLAGS="--xla_force_host_platform_device_count=4")
-    proc = subprocess.run([sys.executable, "-c", code], env=env,
-                          capture_output=True, text=True, timeout=600)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    res = json.loads(proc.stdout.strip().splitlines()[-1])
+    res = run_four_chips(tmp_path, fault=fault)
     assert res["device"]["count"] == 4
     assert res["correct"] is (not fault)
+    if fault:
+        chk = res["checks"]["max_abs_err"]
+        assert res["failed"] > 0 and chk["value"] > chk["limit"]

@@ -48,9 +48,11 @@ def test_traced_run_reads_every_per_layer_metric_found_by_name(
     res = run_tiny(root, trace=True).result
     got = res["metrics"]
     # the CPU has no device plane: the trace readers find nothing and the
-    # harness leaves their metrics out; every other reader reports
+    # harness leaves their metrics out; every other reader of the one-chip
+    # cells reports
     assert set(got) == {"plan_ms", "resident_share", "exec_ms",
-                        "compiles_in_window", "rows_in_window"}
+                        "compiles_in_window", "query_ms", "partial_ms",
+                        "merge_ms", "rows_in_window"}
     assert got["rows_in_window"]["unit"] == "rows"
     assert got["rows_in_window"]["value"] > 0
     assert 0 <= got["resident_share"]["value"] <= 100
@@ -106,3 +108,24 @@ def test_the_rehearsal_covers_the_window_at_the_provided_rate():
         steps = harness.rehearse_horizon(traffic, seconds)
         assert steps == traffic.warmup_steps + \
             seconds * harness.REHEARSE_STEPS_PER_S
+
+
+@pytest.mark.parametrize("n", [5, 130])
+def test_chunks_drawn_over_several_devices_are_the_same_draw(n):
+    """Chunk i is the seed's key folded with i, drawn on the i-th device
+    of a round-robin list: the same data as the one-device draw, at sizes
+    that leave a device or a group short."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    seed = 2_200_000_555
+    one = harness.make_chunks(jax, seed, n, 8, 4, jnp.bfloat16)
+    spread = harness.make_chunks(jax, seed, n, 8, 4, jnp.bfloat16,
+                                 (jax.devices()[0],) * 3)
+    assert len(one) == len(spread) == n
+    key = jax.random.fold_in(jax.random.PRNGKey(seed % 2 ** 32), seed >> 32)
+    for i, (a, b) in enumerate(zip(one, spread)):
+        want = jax.random.normal(jax.random.fold_in(key, i), (8, 4),
+                                 jnp.bfloat16)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(want))
+        np.testing.assert_array_equal(np.asarray(b), np.asarray(want))

@@ -1,5 +1,5 @@
 """The trace reduction, on synthetic traces with known answers and on a
-trace recorded on the CPU."""
+trace recorded on the CPU, and the collective_ms reader over it."""
 
 import glob
 
@@ -42,6 +42,24 @@ def test_busy_and_collective_time_are_means_over_chips():
     # chip 0: the permute only (the fusion merely reads a gather's
     # result); chip 1: permute and gather-done overlap -> [1,4]
     assert s.collective_s == pytest.approx((2e-3 + 3e-3) / 2)
+
+
+def test_collective_ms_is_the_collective_time_per_traced_step():
+    from conftest import BENCH
+    import harness
+    read = harness.metric_reader(BENCH, "collective_ms")
+    window = harness.Window(steps=[], seconds=1.0, compiles=0, chips=2,
+                            peak={}, trace=None)
+    assert read(window) is None                  # no trace: nothing read
+    window.trace = TR.reduce(trace_of({}, [("bench.step", 0, MS)]))
+    assert read(window) is None                  # no device: nothing
+    permute = "%collective-permute.1 = bf16[4] collective-permute(%q)"
+    spans = [("bench.step", 0, 5 * MS), ("bench.step", 5 * MS, 10 * MS)]
+    ops = {0: [(permute, 1 * MS, 3 * MS), (permute, 6 * MS, 7 * MS)],
+           1: [(permute, 2 * MS, 3 * MS), ("%a = f32[] add()", 0, MS)]}
+    window.trace = TR.reduce(trace_of(ops, spans))
+    # (3 + 1) / 2 ms a chip over 2 steps
+    assert read(window) == pytest.approx(1.0)
 
 
 def test_idle_gaps_are_named_by_the_innermost_host_span():
